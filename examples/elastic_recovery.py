@@ -29,7 +29,6 @@ from repro.core.device import CATALOG, Topology
 from repro.core.graph_builders import GraphSpec, build_lm_graph
 from repro.core.qoe import QoESpec
 from repro.scenarios import Scenario
-from repro.launch.mesh import use_mesh
 from repro.launch.steps import make_train_step
 from repro.models.sharding import ShardingRules
 from repro.optim import adamw_init
@@ -64,7 +63,7 @@ def main() -> None:
     ckpt = Checkpointer(tempfile.mkdtemp(), async_save=False)
     mesh8 = make_mesh(8)
     print(f"training on {mesh8.devices.size} devices...")
-    with use_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         params = model.init(jax.random.PRNGKey(0))
         opt = adamw_init(params)
         for step in range(4):
@@ -107,7 +106,7 @@ def main() -> None:
     print(f"restored step {state.step} onto a "
           f"{state.mesh.devices.size}-device mesh (generation "
           f"{state.generation})")
-    with use_mesh(state.mesh):
+    with jax.set_mesh(state.mesh):
         p, o, m = jit_step(state.params, state.opt_state,
                            batch(state.mesh, 99), jnp.asarray(5))
     print(f"training resumed: step 5 loss {float(m['loss']):.4f}")

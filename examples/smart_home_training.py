@@ -29,7 +29,7 @@ from repro.core.cost_model import Workload
 from repro.core.graph_builders import GraphSpec, build_lm_graph
 from repro.core.qoe import QoESpec
 from repro.data import DataConfig, TokenPipeline
-from repro.launch.mesh import make_host_mesh, use_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models.common import count_params
 from repro.optim import adamw_init
@@ -76,7 +76,7 @@ def main() -> None:
     model, train_step = make_train_step(cfg, peak_lr=1e-3,
                                         warmup=max(args.steps // 20, 5),
                                         total=args.steps, remat="none")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         print(f"model: {count_params(params) / 1e6:.1f}M params")
         opt = adamw_init(params)

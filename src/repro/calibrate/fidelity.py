@@ -187,7 +187,6 @@ def execute_layout(case: FidelityCase, layout: Layout, wl: Workload, *,
     import jax.numpy as jnp
     import numpy as np
 
-    from ..launch.mesh import use_mesh
     from ..runtime.pipeline import DoraPipelineExecutor
 
     need = max(dev for _, dev in layout) + 1
@@ -211,7 +210,7 @@ def execute_layout(case: FidelityCase, layout: Layout, wl: Workload, *,
     x = jax.random.normal(
         jax.random.PRNGKey(1),
         (wl.n_microbatches, case.rows(wl), case.d_model), jnp.float32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if wl.training:
             step = jax.jit(jax.value_and_grad(
                 lambda p: ex.loss(p, x, lambda out: jnp.mean(out * out))))

@@ -1,17 +1,16 @@
 """Public kernel entry points with backend dispatch.
 
-On a TPU backend the Pallas kernels compile natively; on CPU they run
-under ``interpret=True`` (the kernel body executes step-by-step — exact
-semantics, no Mosaic) or fall back to the pure-jnp references for bulk
-work. Selection:
+On a TPU backend every entry point runs its compiled Pallas kernel;
+on any other backend it runs the pure-jnp reference from ``ref.py``.
+There is no switch between the two: interpret mode exists only as the
+``interpret=`` argument that tests pass to the kernel modules directly.
 
-* ``REPRO_KERNELS=pallas``    — force Pallas (interpret on CPU)
-* ``REPRO_KERNELS=ref``       — force the jnp references
-* ``REPRO_KERNELS=auto``      — Pallas on TPU, references elsewhere
+The scans fall back to their references only for shapes their blocks
+cannot tile (a sequence that does not divide into chunks, or a chunk
+that is neither the whole sequence nor a multiple of 128 lanes).
 """
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -24,21 +23,14 @@ from .rglru_scan import rglru_scan as _rglru_pallas
 from .ssd_scan import ssd_scan as _ssd_pallas
 
 
-def _mode() -> str:
-    return os.environ.get("REPRO_KERNELS", "auto")
-
-
 def use_pallas() -> bool:
-    m = _mode()
-    if m == "pallas":
-        return True
-    if m == "ref":
-        return False
     return jax.default_backend() == "tpu"
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _tiles(block: int, full: int, multiple: int) -> bool:
+    """A block dim Mosaic can tile: the whole dim, or a divisor of it
+    that is a multiple of the hardware tile."""
+    return block == full or (full % block == 0 and block % multiple == 0)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -46,7 +38,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None) -> jnp.ndarray:
     if use_pallas():
         return _flash_pallas(q, k, v, causal=causal, window=window,
-                             scale=scale, interpret=_interpret())
+                             scale=scale)
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                    scale=scale)
 
@@ -56,7 +48,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
                      scale: Optional[float] = None) -> jnp.ndarray:
     if use_pallas():
         return _decode_pallas(q, k_cache, v_cache, cache_len, window=window,
-                              scale=scale, interpret=_interpret())
+                              scale=scale)
     return ref.decode_attention_ref(q, k_cache, v_cache, cache_len,
                                     window=window, scale=scale)
 
@@ -65,9 +57,8 @@ def ssd_scan(x, a_log, b, c, *, chunk: int = 256
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     S = x.shape[1]
     chunk = min(chunk, S)
-    if use_pallas() and S % chunk == 0:
-        return _ssd_pallas(x, a_log, b, c, chunk=chunk,
-                           interpret=_interpret())
+    if use_pallas() and _tiles(chunk, S, 128):
+        return _ssd_pallas(x, a_log, b, c, chunk=chunk)
     return ref.ssd_scan_ref(x, a_log, b, c, chunk=chunk)
 
 
@@ -75,7 +66,6 @@ def rglru_scan(a_log, b, *, block_t: int = 256
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     _, S, W = a_log.shape
     bt, bw = min(block_t, S), min(512, W)
-    if use_pallas() and S % bt == 0 and W % bw == 0:
-        return _rglru_pallas(a_log, b, block_t=bt, block_w=bw,
-                             interpret=_interpret())
+    if use_pallas() and _tiles(bt, S, 8) and _tiles(bw, W, 128):
+        return _rglru_pallas(a_log, b, block_t=bt, block_w=bw)
     return ref.rglru_scan_ref(a_log, b)

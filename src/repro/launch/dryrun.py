@@ -19,7 +19,7 @@ import jax
 
 from ..configs import ARCH_IDS, ShapeSpec, applicable_shapes, get_config
 from ..models.config import ArchConfig
-from .mesh import make_production_mesh, use_mesh
+from .mesh import make_production_mesh
 from .steps import (batch_structs, make_prefill_step, make_serve_step,
                     make_train_step, param_structs, serve_structs, step_struct)
 
@@ -170,7 +170,7 @@ def run_cell(arch: str, shape: ShapeSpec, multi_pod: bool,
                  "mesh": "2x16x16" if multi_pod else "16x16",
                  "mode": shape.mode, "devices": int(mesh.devices.size)}
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         # 1) full-depth lower + compile — THE dry-run proof + memory truth
         lowered = _lower_cell(cfg, shape, mesh, remat)
         rec["lower_s"] = round(time.time() - t0, 2)

@@ -1,45 +1,27 @@
-"""Production meshes + jax-version compatibility shims.
+"""Production and host meshes.
 
 ``make_production_mesh`` is a FUNCTION so importing this module never
 touches jax device state (the dry-run must set XLA_FLAGS first).
-
-The mesh APIs moved between jax 0.4.x and ≥0.5 (``axis_types`` kwarg,
-``jax.set_mesh``); ``compat_make_mesh`` / ``use_mesh`` paper over the
-difference so the same launch code runs on both.
+Activate a mesh with ``jax.set_mesh(mesh)``.
 """
 from __future__ import annotations
 
 import jax
 
 
-def compat_make_mesh(shape, axes, **kw):
-    """``jax.make_mesh`` with ``axis_types`` only where supported."""
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes), **kw)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes, **kw)
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` on jax ≥0.6,
-    ``jax.sharding.use_mesh`` on 0.5.x, the Mesh context itself on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a 1×N ('data','model') mesh —
     used by CPU smoke tests and the examples."""
     n = len(jax.devices())
-    return compat_make_mesh((1, n), ("data", "model"))
+    return _auto_mesh((1, n), ("data", "model"))

@@ -1,9 +1,9 @@
 """Reference attention implementations (pure jnp, GSPMD-friendly).
 
 These are the oracles for the Pallas kernels in ``repro.kernels`` and
-the path used by the 512-device dry-run (Pallas TPU kernels cannot lower
-on the CPU backend; ``attn_impl='pallas'`` swaps the kernels in when a
-TPU backend is present).
+the path used off the TPU, the 512-device dry-run included (Pallas TPU
+kernels cannot lower on the CPU backend). On a TPU backend the dispatch
+points below call the compiled kernels.
 
 Layouts: q (B, S, H, hd); k/v (B, T, KV, hd). GQA groups are computed
 via einsum without materializing repeated K/V.
@@ -66,14 +66,25 @@ def gqa_attention_chunked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                           causal: bool = True, window: Optional[int] = None,
                           prefix_len: int = 0, q_chunk: int = 1024,
                           scale: Optional[float] = None) -> jnp.ndarray:
-    """Query-chunked attention: bounds live score memory at
-    (B, H, q_chunk, T) — the pure-jnp stand-in for the flash kernel on
-    long-sequence prefill/training."""
+    """Long-sequence attention for prefill/training: the flash kernel on
+    a TPU backend (causal, no prefix), else the query-chunked reference."""
     if causal and prefix_len == 0:
         ops = _pallas_ops()
         if ops is not None:
             return ops.flash_attention(q, k, v, causal=True, window=window,
                                        scale=scale)
+    return chunked_attention_ref(q, k, v, causal=causal, window=window,
+                                 prefix_len=prefix_len, q_chunk=q_chunk,
+                                 scale=scale)
+
+
+def chunked_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+                          causal: bool = True, window: Optional[int] = None,
+                          prefix_len: int = 0, q_chunk: int = 1024,
+                          scale: Optional[float] = None) -> jnp.ndarray:
+    """Query-chunked attention (pure jnp, never dispatches): bounds live
+    score memory at (B, H, q_chunk, T) — the flash kernel's stand-in off
+    the TPU and the function its backward differentiates."""
     B, S, H, hd = q.shape
     if S % q_chunk:
         return gqa_attention(q, k, v, causal=causal, window=window,

@@ -51,9 +51,18 @@ def _conv_causal(x: jnp.ndarray, w: jnp.ndarray,
 
 def _rglru_scan(xg: jnp.ndarray, a_log: jnp.ndarray,
                 h0: jnp.ndarray | None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """h_t = a_t h_{t−1} + b_t over seq axis 1. a_log: log a_t (f32)."""
-    a = jnp.exp(a_log)
+    """RG-LRU over seq axis 1: the gated input b_t = sqrt(1 − a_t²)·xg_t
+    into the linear recurrence. a_log: log a_t (f32)."""
     b = jnp.sqrt(jnp.clip(1.0 - jnp.exp(2.0 * a_log), 1e-12)) * xg
+    return linear_scan(a_log, b, h0)
+
+
+def linear_scan(a_log: jnp.ndarray, b: jnp.ndarray,
+                h0: jnp.ndarray | None = None
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """h_t = a_t h_{t−1} + b_t over seq axis 1, a_t = exp(a_log_t).
+    Returns (h, h_last)."""
+    a = jnp.exp(a_log)
 
     def comb(e1, e2):
         a1, b1 = e1

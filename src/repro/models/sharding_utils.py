@@ -5,24 +5,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """Version-agnostic ``AbstractMesh`` construction.
-
-    jax ≥ 0.5 takes ``AbstractMesh(axis_sizes, axis_names)``; jax 0.4.x
-    takes a single ``shape_tuple`` of (name, size) pairs. Both expose
-    ``axis_names`` / ``axis_sizes`` on the result.
-    """
-    from jax.sharding import AbstractMesh
-
-    axis_sizes = tuple(axis_sizes)
-    axis_names = tuple(axis_names)
-    try:
-        return AbstractMesh(axis_sizes, axis_names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """A device-free mesh of the given shape (for sharding-rule checks)."""
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def mesh_axes() -> tuple:

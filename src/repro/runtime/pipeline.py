@@ -21,11 +21,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map                    # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.plans import ParallelismPlan
 
@@ -55,7 +52,8 @@ class PipelineSpec:
 
 
 def _pad_stage_params(stacked: Any, spec: PipelineSpec) -> Any:
-    """(L, ...) stacked layer params → (S, pad, ...), zero-padded."""
+    """(L, ...) stacked layer params → (S, pad, ...) host arrays,
+    zero-padded."""
     bounds = np.cumsum((0,) + spec.layers_per_stage)
 
     def fn(x):
@@ -63,7 +61,7 @@ def _pad_stage_params(stacked: Any, spec: PipelineSpec) -> Any:
         for s in range(spec.n_stages):
             lo, hi = bounds[s], bounds[s + 1]
             out[s, : hi - lo] = np.asarray(x[lo:hi])
-        return jnp.asarray(out)
+        return out
     return jax.tree.map(fn, stacked)
 
 
@@ -88,7 +86,10 @@ class DoraPipelineExecutor:
 
     # -- parameter packing ------------------------------------------------------
     def pack_params(self, stacked_params: Any) -> Any:
-        return _pad_stage_params(stacked_params, self.spec)
+        """Re-pack per stage and place each stage's block on its own
+        device of the 'stage' axis (no device holds another's layers)."""
+        return jax.device_put(_pad_stage_params(stacked_params, self.spec),
+                              NamedSharding(self.mesh, P("stage")))
 
     # -- forward -------------------------------------------------------------------
     def forward(self, stage_params: Any, x: jnp.ndarray) -> jnp.ndarray:
@@ -99,18 +100,11 @@ class DoraPipelineExecutor:
         S, M = spec.n_stages, spec.n_microbatches
         n_valid = jnp.asarray(spec.layers_per_stage)
 
-        # jax ≥0.7 calls the replication check ``check_vma``; older jax
-        # calls it ``check_rep`` — disable whichever this jax has.
-        import inspect
-        check_kw = ("check_vma" if "check_vma"
-                    in inspect.signature(shard_map).parameters
-                    else "check_rep")
-
         @functools.partial(
             shard_map, mesh=self.mesh,
             in_specs=(P("stage"), P(None)),
             out_specs=P(None),
-            **{check_kw: False})
+            check_vma=False)
         def run(params, xs):
             params = jax.tree.map(lambda a: a[0], params)   # local stage block
             stage_id = jax.lax.axis_index("stage")

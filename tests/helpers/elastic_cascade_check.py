@@ -20,7 +20,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import Checkpointer
 from repro.configs import reduced_config
-from repro.launch.mesh import use_mesh
 from repro.launch.steps import make_train_step
 from repro.models.sharding import ShardingRules
 from repro.optim import adamw_init
@@ -58,7 +57,7 @@ def main():
     ckpt = Checkpointer(tmp, async_save=False)
 
     mesh = make_mesh(8)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         opt = adamw_init(params)
         for step in range(3):
@@ -87,7 +86,7 @@ def main():
                                       np.asarray(b, np.float32))
 
     # training continues on the 4-mesh and checkpoints one more step
-    with use_mesh(state.mesh):
+    with jax.set_mesh(state.mesh):
         p4, o4, m4 = jit_step(state.params, state.opt_state,
                               batch_for(state.mesh, 10), jnp.asarray(3))
         assert np.isfinite(float(m4["loss"]))
@@ -109,7 +108,7 @@ def main():
                                       np.asarray(b, np.float32))
 
     # the twice-shrunk mesh still trains
-    with use_mesh(state.mesh):
+    with jax.set_mesh(state.mesh):
         _, _, m2 = jit_step(state.params, state.opt_state,
                             batch_for(state.mesh, 20), jnp.asarray(4))
     assert np.isfinite(float(m2["loss"]))

@@ -19,7 +19,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import Checkpointer, latest_step
 from repro.configs import reduced_config
-from repro.launch.mesh import use_mesh
 from repro.launch.steps import make_train_step
 from repro.models.sharding import ShardingRules
 from repro.optim import adamw_init
@@ -57,7 +56,7 @@ def main():
     ckpt = Checkpointer(tmp, async_save=False)
 
     mesh8 = make_mesh(8)
-    with use_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         params = model.init(jax.random.PRNGKey(0))
         opt = adamw_init(params)
         for step in range(3):
@@ -90,7 +89,7 @@ def main():
                                       np.asarray(b, np.float32))
 
     # training resumes on the shrunk mesh
-    with use_mesh(new_mesh):
+    with jax.set_mesh(new_mesh):
         p2, o2, m2 = jit_step(state.params, state.opt_state,
                               batch_for(new_mesh, 10), jnp.asarray(4))
     assert np.isfinite(float(m2["loss"]))

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.plans import ParallelismPlan, Stage
-from repro.launch.mesh import use_mesh
 from repro.runtime.pipeline import DoraPipelineExecutor
 
 S, L, D = 4, 8, 16          # stages, layers, width
@@ -45,7 +44,7 @@ def main():
     ex = DoraPipelineExecutor(plan, L, mesh, layer_fn)
     packed = ex.pack_params(stacked)
     x = jax.random.normal(jax.random.PRNGKey(1), (M, MB, D))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = ex.forward(packed, x)
 
     # sequential reference

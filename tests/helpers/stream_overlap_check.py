@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.plans import ParallelismPlan, Stage
-from repro.launch.mesh import use_mesh
 from repro.runtime.pipeline import DoraPipelineExecutor
 
 S, L, D = 4, 8, 16          # stages, layers, width
@@ -54,7 +53,7 @@ def measured_span() -> float:
     ex = DoraPipelineExecutor(plan, L, mesh, layer_fn)
     packed = ex.pack_params(stacked)
     x = jax.random.normal(jax.random.PRNGKey(1), (M, MB, D))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jax.block_until_ready(ex.forward(packed, x))     # compile
         t0 = time.perf_counter()
         reps = 3

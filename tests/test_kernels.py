@@ -154,3 +154,52 @@ def test_rglru_matches_sequential():
         hs = a[:, t] * hs + np.asarray(b[:, t])
         expected[:, t] = hs
     np.testing.assert_allclose(h, expected, atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------- gradients
+# Each kernel's custom_vjp backward must equal jax.grad of its reference.
+def _grad_check(kernel_fn, ref_fn, args, argnums, tol):
+    def loss(fn):
+        def f(*a):
+            outs = fn(*a)
+            outs = outs if isinstance(outs, tuple) else (outs,)
+            return sum(jnp.sum(jnp.sin(o.astype(jnp.float32))) for o in outs)
+        return f
+    got = jax.grad(loss(kernel_fn), argnums=argnums)(*args)
+    want = jax.grad(loss(ref_fn), argnums=argnums)(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("window", [None, 64])
+def test_flash_attention_grad(window):
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (1, 256, 4, 32), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 256, 2, 32), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 256, 2, 32), jnp.float32)
+    _grad_check(
+        lambda q, k, v: flash_attention(q, k, v, window=window,
+                                        interpret=True),
+        lambda q, k, v: ref.flash_attention_ref(q, k, v, window=window),
+        (q, k, v), (0, 1, 2), 1e-4)
+
+
+def test_ssd_scan_grad():
+    ks = jax.random.split(KEY, 4)
+    x = jax.random.normal(ks[0], (1, 128, 2, 16)) * 0.2
+    a = -jnp.abs(jax.random.normal(ks[1], (1, 128, 2))) * 0.2
+    b = jax.random.normal(ks[2], (1, 128, 1, 32)) * 0.2
+    c = jax.random.normal(ks[3], (1, 128, 1, 32)) * 0.2
+    _grad_check(
+        lambda *a_: ssd_scan(*a_, chunk=32, interpret=True),
+        lambda *a_: ref.ssd_scan_ref(*a_, chunk=32),
+        (x, a, b, c), (0, 1, 2, 3), 1e-4)
+
+
+def test_rglru_scan_grad():
+    ks = jax.random.split(KEY, 2)
+    a_log = -jnp.abs(jax.random.normal(ks[0], (1, 128, 128))) * 0.5
+    b = jax.random.normal(ks[1], (1, 128, 128))
+    _grad_check(
+        lambda a_, b_: rglru_scan(a_, b_, block_t=32, interpret=True),
+        ref.rglru_scan_ref, (a_log, b), (0, 1), 1e-4)
