@@ -1,0 +1,19 @@
+"""The bytes a decode step must move (weights once, the live cache or
+state read, the new entry written), averaged over the round's positions,
+over the mean device time of one decode step (the jitted ``serve_step``,
+traced window) and the chip's peak HBM bandwidth."""
+import numpy as np
+
+from bench.measure import serve_shape, share_of_peak, step_dev_ms
+from bench.work import decode_attention, model
+
+
+def read(run):
+    ms = step_dev_ms(run, "serve_step")
+    if ms is None:
+        return None
+    a = run.arch
+    B, P, G, _ = serve_shape(run)
+    moved = np.mean([model.decode_bytes(a, B, live)
+                     for live in decode_attention.live_entries(P, G, a.get("window"))])
+    return share_of_peak(moved, ms / 1e3, run.peaks["hbm_bytes_per_s"])
