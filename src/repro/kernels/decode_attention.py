@@ -121,6 +121,7 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     )
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, d), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -118,6 +118,7 @@ def _flash_forward(q, k, v, causal, window, scale, block_q, block_k,
     vt = v.transpose(0, 2, 1, 3)
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((None, None, bq, d),

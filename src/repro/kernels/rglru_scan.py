@@ -76,6 +76,7 @@ def _rglru_forward(a_log, b, block_t, block_w, interpret):
     kernel = functools.partial(_rglru_kernel, block_t=bt, n_tblocks=nt)
     h, h_last = pl.pallas_call(
         kernel,
+        name="rglru_scan",
         grid=(B, nw, nt),
         in_specs=[
             pl.BlockSpec((None, bt, bw), lambda bi, iw, it: (bi, it, iw)),

@@ -99,6 +99,7 @@ def _ssd_forward(x, a_log, b, c, chunk, interpret):
     kernel = functools.partial(_ssd_kernel, chunk=L, n_chunks=nc)
     y, hfin = pl.pallas_call(
         kernel,
+        name="ssd_scan",
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((None, None, L, P), lambda bi, h, ic: (bi, h, ic, 0)),

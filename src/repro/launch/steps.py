@@ -40,8 +40,9 @@ def make_train_step(cfg: ArchConfig, *, peak_lr: float = 3e-4,
             loss, metrics = model.loss(p, batch, remat=remat)
             return loss, metrics
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        lr = warmup_cosine(step, peak_lr=peak_lr, warmup=warmup, total=total)
-        params, opt_state, om = adamw_update(grads, opt_state, params, lr, opt)
+        with jax.named_scope("optimizer"):
+            lr = warmup_cosine(step, peak_lr=peak_lr, warmup=warmup, total=total)
+            params, opt_state, om = adamw_update(grads, opt_state, params, lr, opt)
         out = {"loss": loss, "lr": lr, **metrics, **om}
         return params, opt_state, out
 
@@ -61,7 +62,8 @@ def make_prefill_step(cfg: ArchConfig):
                                           extra_embeddings=kw["extra_embeddings"])
         else:
             logits, cache = model.prefill(params, tokens, cache)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_tok, cache
 
     return model, prefill_step
@@ -72,7 +74,8 @@ def make_serve_step(cfg: ArchConfig):
 
     def serve_step(params, token, cache, pos):
         logits, cache = model.decode(params, token, cache, pos)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_tok, cache
 
     return model, serve_step

@@ -83,32 +83,36 @@ def apply_rglru(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
                 state: Dict | None = None) -> Tuple[jnp.ndarray, Dict]:
     """x: (B, S, D) → (out, new_state {lru (B,W) f32, conv (B,K−1,W)})."""
     B, S, _ = x.shape
-    gate = jax.nn.gelu(x @ p["w_gate_branch"], approximate=True)
-    proj = x @ p["w_in"]
-    tail = state["conv"] if state is not None else None
-    u = _conv_causal(proj, p["conv_w"], tail)
-    K = cfg.conv_width
-    hist = proj if tail is None else jnp.concatenate([tail, proj], axis=1)
-    if hist.shape[1] < K - 1:
-        padz = jnp.zeros((B, K - 1 - hist.shape[1], hist.shape[2]), hist.dtype)
-        hist = jnp.concatenate([padz, hist], axis=1)
-    new_conv = hist[:, -(K - 1):]
+    with jax.named_scope("in_proj"):
+        gate = jax.nn.gelu(x @ p["w_gate_branch"], approximate=True)
+        proj = x @ p["w_in"]
+    with jax.named_scope("conv"):
+        tail = state["conv"] if state is not None else None
+        u = _conv_causal(proj, p["conv_w"], tail)
+        K = cfg.conv_width
+        hist = proj if tail is None else jnp.concatenate([tail, proj], axis=1)
+        if hist.shape[1] < K - 1:
+            padz = jnp.zeros((B, K - 1 - hist.shape[1], hist.shape[2]), hist.dtype)
+            hist = jnp.concatenate([padz, hist], axis=1)
+        new_conv = hist[:, -(K - 1):]
 
-    r = jax.nn.sigmoid((u @ p["wa"]).astype(jnp.float32) + p["ba"])
-    i = jax.nn.sigmoid((u @ p["wx"]).astype(jnp.float32) + p["bx"])
-    a_log = -_C * jax.nn.softplus(p["lam"]) * r                  # (B,S,W) f32
-    xg = i * u.astype(jnp.float32)
-    h0 = state["lru"] if state is not None else None
-    if h0 is None and S % min(256, S) == 0:
-        from ..kernels import ops as _kops       # lazy: ref.py imports us
-        if _kops.use_pallas():
-            b = jnp.sqrt(jnp.clip(1.0 - jnp.exp(2.0 * a_log), 1e-12)) * xg
-            h, h_last = _kops.rglru_scan(a_log, b, block_t=min(256, S))
+    with jax.named_scope("scan"):
+        r = jax.nn.sigmoid((u @ p["wa"]).astype(jnp.float32) + p["ba"])
+        i = jax.nn.sigmoid((u @ p["wx"]).astype(jnp.float32) + p["bx"])
+        a_log = -_C * jax.nn.softplus(p["lam"]) * r                  # (B,S,W) f32
+        xg = i * u.astype(jnp.float32)
+        h0 = state["lru"] if state is not None else None
+        if h0 is None and S % min(256, S) == 0:
+            from ..kernels import ops as _kops       # lazy: ref.py imports us
+            if _kops.use_pallas():
+                b = jnp.sqrt(jnp.clip(1.0 - jnp.exp(2.0 * a_log), 1e-12)) * xg
+                h, h_last = _kops.rglru_scan(a_log, b, block_t=min(256, S))
+            else:
+                h, h_last = _rglru_scan(xg, a_log, h0)
         else:
             h, h_last = _rglru_scan(xg, a_log, h0)
-    else:
-        h, h_last = _rglru_scan(xg, a_log, h0)
-    y = (h.astype(x.dtype) * gate) @ p["w_out"]
+    with jax.named_scope("out"):
+        y = (h.astype(x.dtype) * gate) @ p["w_out"]
     return y, {"lru": h_last, "conv": new_conv}
 
 

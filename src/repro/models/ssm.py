@@ -156,48 +156,52 @@ def apply_mamba2(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
     B, S, D = x.shape
     din, g, n, h = cfg.d_inner, cfg.ssm_ngroups, cfg.ssm_state, cfg.ssm_nheads
     pdim = cfg.ssm_headdim
-    proj = x @ p["in_proj"]
-    z, xc, bc, cc, dt = jnp.split(
-        proj, [din, 2 * din, 2 * din + g * n, 2 * din + 2 * g * n], axis=-1)
-    conv_in = jnp.concatenate([xc, bc, cc], axis=-1)
-    tail = state["conv"] if state is not None else None
-    conv_out = _causal_conv(conv_in, p["conv_w"], tail)
-    K = cfg.ssm_conv
-    hist = conv_in if tail is None else jnp.concatenate([tail, conv_in], axis=1)
-    if hist.shape[1] < K - 1:       # very short prefill: left-pad with zeros
-        pad = jnp.zeros((B, K - 1 - hist.shape[1], hist.shape[2]), hist.dtype)
-        hist = jnp.concatenate([pad, hist], axis=1)
-    new_conv = hist[:, -(K - 1):]
-    xc, bc, cc = jnp.split(conv_out, [din, din + g * n], axis=-1)
+    with jax.named_scope("in_proj"):
+        proj = x @ p["in_proj"]
+        z, xc, bc, cc, dt = jnp.split(
+            proj, [din, 2 * din, 2 * din + g * n, 2 * din + 2 * g * n], axis=-1)
+    with jax.named_scope("conv"):
+        conv_in = jnp.concatenate([xc, bc, cc], axis=-1)
+        tail = state["conv"] if state is not None else None
+        conv_out = _causal_conv(conv_in, p["conv_w"], tail)
+        K = cfg.ssm_conv
+        hist = conv_in if tail is None else jnp.concatenate([tail, conv_in], axis=1)
+        if hist.shape[1] < K - 1:       # very short prefill: left-pad with zeros
+            pad = jnp.zeros((B, K - 1 - hist.shape[1], hist.shape[2]), hist.dtype)
+            hist = jnp.concatenate([pad, hist], axis=1)
+        new_conv = hist[:, -(K - 1):]
+        xc, bc, cc = jnp.split(conv_out, [din, din + g * n], axis=-1)
 
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])       # (B,S,H)
-    a = -jnp.exp(p["a_log"])                                          # (H,)
-    a_log_steps = dt * a                                              # (B,S,H) ≤ 0
-    xh = xc.reshape(B, S, h, pdim)
-    xdt = xh * dt[..., None].astype(x.dtype)
-    bmat = bc.reshape(B, S, g, n)
-    cmat = cc.reshape(B, S, g, n)
+    with jax.named_scope("scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])       # (B,S,H)
+        a = -jnp.exp(p["a_log"])                                          # (H,)
+        a_log_steps = dt * a                                              # (B,S,H) ≤ 0
+        xh = xc.reshape(B, S, h, pdim)
+        xdt = xh * dt[..., None].astype(x.dtype)
+        bmat = bc.reshape(B, S, g, n)
+        cmat = cc.reshape(B, S, g, n)
 
-    h0 = state["ssm"] if state is not None else None
-    chunk = min(cfg.ssm_chunk, S)
-    if h0 is None and S % chunk == 0:
-        from ..kernels import ops as _kops       # lazy: ref.py imports us
-        if _kops.use_pallas():
-            y, hfin = _kops.ssd_scan(xdt, a_log_steps, bmat, cmat, chunk=chunk)
-        elif S // chunk > 4:
-            # long sequences: sequential chunk scan — one (l, l) decay
-            # matrix live at a time instead of all nc at once
+        h0 = state["ssm"] if state is not None else None
+        chunk = min(cfg.ssm_chunk, S)
+        if h0 is None and S % chunk == 0:
+            from ..kernels import ops as _kops       # lazy: ref.py imports us
+            if _kops.use_pallas():
+                y, hfin = _kops.ssd_scan(xdt, a_log_steps, bmat, cmat, chunk=chunk)
+            elif S // chunk > 4:
+                # long sequences: sequential chunk scan — one (l, l) decay
+                # matrix live at a time instead of all nc at once
+                y, hfin = ssd_scanned(xdt, a_log_steps, bmat, cmat, chunk, h0)
+            else:
+                y, hfin = ssd_chunked(xdt, a_log_steps, bmat, cmat, chunk=chunk)
+        elif S % chunk == 0 and S // chunk > 4:
             y, hfin = ssd_scanned(xdt, a_log_steps, bmat, cmat, chunk, h0)
         else:
-            y, hfin = ssd_chunked(xdt, a_log_steps, bmat, cmat, chunk=chunk)
-    elif S % chunk == 0 and S // chunk > 4:
-        y, hfin = ssd_scanned(xdt, a_log_steps, bmat, cmat, chunk, h0)
-    else:
-        y, hfin = ssd_chunked(xdt, a_log_steps, bmat, cmat, chunk=chunk, h0=h0)
-    y = y + xh * p["d_skip"][None, None, :, None].astype(x.dtype)
-    y = y.reshape(B, S, din)
-    y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
-    out = y @ p["out_proj"]
+            y, hfin = ssd_chunked(xdt, a_log_steps, bmat, cmat, chunk=chunk, h0=h0)
+        y = y + xh * p["d_skip"][None, None, :, None].astype(x.dtype)
+    with jax.named_scope("out"):
+        y = y.reshape(B, S, din)
+        y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
+        out = y @ p["out_proj"]
     return out, {"ssm": hfin, "conv": new_conv}
 
 
@@ -207,29 +211,35 @@ def apply_mamba2_decode(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
     B, S, D = x.shape
     din, g, n, h = cfg.d_inner, cfg.ssm_ngroups, cfg.ssm_state, cfg.ssm_nheads
     pdim = cfg.ssm_headdim
-    proj = x @ p["in_proj"]
-    z, xc, bc, cc, dt = jnp.split(
-        proj, [din, 2 * din, 2 * din + g * n, 2 * din + 2 * g * n], axis=-1)
-    conv_in = jnp.concatenate([xc, bc, cc], axis=-1)                 # (B,1,C)
-    window = jnp.concatenate([state["conv"], conv_in], axis=1)       # (B,K,C)
-    w = p["conv_w"]
-    conv_out = jax.nn.silu(jnp.einsum("bkc,kc->bc", window, w))[:, None]
-    new_conv = window[:, 1:]
-    xc, bc, cc = jnp.split(conv_out, [din, din + g * n], axis=-1)
+    with jax.named_scope("in_proj"):
+        proj = x @ p["in_proj"]
+        z, xc, bc, cc, dt = jnp.split(
+            proj, [din, 2 * din, 2 * din + g * n, 2 * din + 2 * g * n], axis=-1)
+    with jax.named_scope("conv"):
+        conv_in = jnp.concatenate([xc, bc, cc], axis=-1)                 # (B,1,C)
+        window = jnp.concatenate([state["conv"], conv_in], axis=1)       # (B,K,C)
+        w = p["conv_w"]
+        conv_out = jax.nn.silu(jnp.einsum("bkc,kc->bc", window, w))[:, None]
+        new_conv = window[:, 1:]
+        xc, bc, cc = jnp.split(conv_out, [din, din + g * n], axis=-1)
 
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])[:, 0]   # (B,H)
-    a = jnp.exp(dt * -jnp.exp(p["a_log"]))                              # (B,H)
-    xh = xc.reshape(B, h, pdim)
-    bmat = jnp.repeat(bc.reshape(B, g, n), h // g, axis=1)              # (B,H,N)
-    cmat = jnp.repeat(cc.reshape(B, g, n), h // g, axis=1)
-    hs = state["ssm"].astype(jnp.float32)
-    hs = a[..., None, None] * hs + (dt[..., None] * xh.astype(jnp.float32)
-                                    )[..., None] * bmat[:, :, None, :].astype(jnp.float32)
-    y = jnp.einsum("bhpn,bhn->bhp", hs, cmat.astype(jnp.float32)).astype(x.dtype)
-    y = y + xh * p["d_skip"][None, :, None].astype(x.dtype)
-    y = y.reshape(B, 1, din)
-    y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
-    return y @ p["out_proj"], {"ssm": hs.astype(state["ssm"].dtype), "conv": new_conv}
+    with jax.named_scope("scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])[:, 0]   # (B,H)
+        a = jnp.exp(dt * -jnp.exp(p["a_log"]))                              # (B,H)
+        xh = xc.reshape(B, h, pdim)
+        bmat = jnp.repeat(bc.reshape(B, g, n), h // g, axis=1)              # (B,H,N)
+        cmat = jnp.repeat(cc.reshape(B, g, n), h // g, axis=1)
+        hs = state["ssm"].astype(jnp.float32)
+        hs = a[..., None, None] * hs + (dt[..., None] * xh.astype(jnp.float32)
+                                        )[..., None] * bmat[:, :, None, :].astype(jnp.float32)
+        y = jnp.einsum("bhpn,bhn->bhp", hs, cmat.astype(jnp.float32)).astype(x.dtype)
+        y = y + xh * p["d_skip"][None, :, None].astype(x.dtype)
+        new_ssm = hs.astype(state["ssm"].dtype)
+    with jax.named_scope("out"):
+        y = y.reshape(B, 1, din)
+        y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
+        out = y @ p["out_proj"]
+    return out, {"ssm": new_ssm, "conv": new_conv}
 
 
 def mamba2_state_shape(cfg: ArchConfig, batch: int, dtype):

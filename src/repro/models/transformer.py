@@ -13,6 +13,14 @@ Layer stacks run under ``jax.lax.scan`` with stacked parameters (compile
 time at 512 devices stays flat in depth); heterogeneous-pattern models
 (RecurrentGemma 2:1, DeepSeek dense-first) scan over *pattern units*
 with the remainder unrolled.
+
+Every op runs under a ``jax.named_scope`` of one vocabulary, so that a
+profile names the sublayer that issued each device op: ``embed``,
+``layers`` (the scan and the unrolled blocks), inside it ``mixer``
+(attention and MLA: ``qkv``, ``cache``, ``attention``, ``out``; SSD and
+RG-LRU: ``in_proj``, ``conv``, ``scan``, ``out``) and ``mlp`` or
+``moe``, then ``head`` and ``loss``; the train step adds ``optimizer``.
+Scopes change op metadata only, not the compiled program.
 """
 from __future__ import annotations
 
@@ -110,42 +118,53 @@ def apply_attn(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
                cross_kv: Optional[Tuple] = None) -> Tuple[jnp.ndarray, Optional[Dict]]:
     B, S, D = x.shape
     if cross_kv is not None:          # encoder-decoder cross attention
-        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+        with jax.named_scope("qkv"):
+            q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
         k, v = cross_kv
-        o = attn_lib.gqa_attention(q, k, v, causal=False)
-        return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), None
+        with jax.named_scope("attention"):
+            o = attn_lib.gqa_attention(q, k, v, causal=False)
+        with jax.named_scope("out"):
+            return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), None
 
     if mode == "decode":
         positions = pos[:, None] if pos.ndim == 1 else pos
-        q, k, v = _project_qkv(p, x, cfg, positions)
+        with jax.named_scope("qkv"):
+            q, k, v = _project_qkv(p, x, cfg, positions)
         t_buf = cache["k"].shape[1]
         ring = window is not None and t_buf <= window
-        slot = pos % t_buf if ring else pos
-        kc = _write_cache(cache["k"], k, slot)
-        vc = _write_cache(cache["v"], v, slot)
-        if ring:
-            # ring holds exactly the in-window tokens; no window re-mask
-            valid = jnp.minimum(pos + 1, t_buf)
-            o = attn_lib.decode_attention(q, kc, vc, valid, window=None)
-        else:
-            o = attn_lib.decode_attention(q, kc, vc, pos + 1, window=window)
-        out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        with jax.named_scope("cache"):
+            slot = pos % t_buf if ring else pos
+            kc = _write_cache(cache["k"], k, slot)
+            vc = _write_cache(cache["v"], v, slot)
+        with jax.named_scope("attention"):
+            if ring:
+                # ring holds exactly the in-window tokens; no window re-mask
+                valid = jnp.minimum(pos + 1, t_buf)
+                o = attn_lib.decode_attention(q, kc, vc, valid, window=None)
+            else:
+                o = attn_lib.decode_attention(q, kc, vc, pos + 1, window=window)
+        with jax.named_scope("out"):
+            out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
         return out, {"k": kc, "v": vc}
 
-    positions = jnp.arange(S)[None, :]
-    q, k, v = _project_qkv(p, x, cfg, positions)
-    if S > cfg.attn_chunk:
-        o = attn_lib.gqa_attention_chunked(q, k, v, causal=True, window=window,
-                                           prefix_len=prefix_len,
-                                           q_chunk=cfg.attn_chunk // 4)
-    else:
-        o = attn_lib.gqa_attention(q, k, v, causal=True, window=window,
-                                   prefix_len=prefix_len)
-    out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    with jax.named_scope("qkv"):
+        positions = jnp.arange(S)[None, :]
+        q, k, v = _project_qkv(p, x, cfg, positions)
+    with jax.named_scope("attention"):
+        if S > cfg.attn_chunk:
+            o = attn_lib.gqa_attention_chunked(q, k, v, causal=True, window=window,
+                                               prefix_len=prefix_len,
+                                               q_chunk=cfg.attn_chunk // 4)
+        else:
+            o = attn_lib.gqa_attention(q, k, v, causal=True, window=window,
+                                       prefix_len=prefix_len)
+    with jax.named_scope("out"):
+        out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     new_cache = None
     if mode == "prefill":
-        kc = _fit_cache(cache["k"], k)
-        vc = _fit_cache(cache["v"], v)
+        with jax.named_scope("cache"):
+            kc = _fit_cache(cache["k"], k)
+            vc = _fit_cache(cache["v"], v)
         new_cache = {"k": kc, "v": vc}
     return out, new_cache
 
@@ -172,69 +191,83 @@ def _fit_cache(cache: jnp.ndarray, kv: jnp.ndarray) -> jnp.ndarray:
 def apply_mla_block(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
                     cache: Optional[Dict], pos) -> Tuple[jnp.ndarray, Optional[Dict]]:
     B, S, D = x.shape
-    cq = rms_norm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps)
-    kv_a = x @ p["wkv_a"]
-    ckv, k_rope = jnp.split(kv_a, [cfg.kv_lora_rank], axis=-1)
-    ckv = rms_norm(ckv, p["kv_norm"], cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        cq = rms_norm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps)
+        kv_a = x @ p["wkv_a"]
+        ckv, k_rope = jnp.split(kv_a, [cfg.kv_lora_rank], axis=-1)
+        ckv = rms_norm(ckv, p["kv_norm"], cfg.norm_eps)
     if mode == "decode":
-        positions = pos[:, None]
-        k_rope_rot = apply_rope(k_rope[:, :, None, :], positions,
-                                cfg.rope_theta)[:, :, 0]
-        ckv_c = _write_cache(cache["ckv"], ckv, pos)
-        kr_c = _write_cache(cache["krope"], k_rope_rot, pos)
-        o = attn_lib.mla_decode(cq, ckv_c, kr_c, pos + 1,
-                                p["wq_nope"], p["wq_rope"], p["wk_nope"], p["wv"],
-                                rope_theta=cfg.rope_theta)
-        return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), {"ckv": ckv_c, "krope": kr_c}
-    o = attn_lib.mla_prefill(cq, ckv, k_rope, p["wq_nope"], p["wq_rope"],
-                             p["wk_nope"], p["wv"], rope_theta=cfg.rope_theta,
-                             q_chunk=cfg.attn_chunk // 4 if S > cfg.attn_chunk else None)
-    out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        with jax.named_scope("qkv"):
+            positions = pos[:, None]
+            k_rope_rot = apply_rope(k_rope[:, :, None, :], positions,
+                                    cfg.rope_theta)[:, :, 0]
+        with jax.named_scope("cache"):
+            ckv_c = _write_cache(cache["ckv"], ckv, pos)
+            kr_c = _write_cache(cache["krope"], k_rope_rot, pos)
+        with jax.named_scope("attention"):
+            o = attn_lib.mla_decode(cq, ckv_c, kr_c, pos + 1,
+                                    p["wq_nope"], p["wq_rope"], p["wk_nope"], p["wv"],
+                                    rope_theta=cfg.rope_theta)
+        with jax.named_scope("out"):
+            out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        return out, {"ckv": ckv_c, "krope": kr_c}
+    with jax.named_scope("attention"):
+        o = attn_lib.mla_prefill(cq, ckv, k_rope, p["wq_nope"], p["wq_rope"],
+                                 p["wk_nope"], p["wv"], rope_theta=cfg.rope_theta,
+                                 q_chunk=cfg.attn_chunk // 4 if S > cfg.attn_chunk else None)
+    with jax.named_scope("out"):
+        out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     new_cache = None
     if mode == "prefill":
-        positions = jnp.arange(S)[None, :]
-        k_rope_rot = apply_rope(k_rope[:, :, None, :], positions,
-                                cfg.rope_theta)[:, :, 0]
-        new_cache = {"ckv": _fit_cache(cache["ckv"], ckv),
-                     "krope": _fit_cache(cache["krope"], k_rope_rot)}
+        with jax.named_scope("qkv"):
+            positions = jnp.arange(S)[None, :]
+            k_rope_rot = apply_rope(k_rope[:, :, None, :], positions,
+                                    cfg.rope_theta)[:, :, 0]
+        with jax.named_scope("cache"):
+            new_cache = {"ckv": _fit_cache(cache["ckv"], ckv),
+                         "krope": _fit_cache(cache["krope"], k_rope_rot)}
     return out, new_cache
 
 
 def apply_block(p: Dict, x: jnp.ndarray, cfg: ArchConfig, kind: str, *,
                 mode: str = "train", cache=None, pos=None,
                 prefix_len: int = 0) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
-    """Returns (x_out, new_cache, aux_loss)."""
+    """Returns (x_out, new_cache, aux_loss). The mixer and the
+    feed-forward each run under a named scope (``mixer``, ``mlp`` or
+    ``moe``) that covers their pre-norm and residual."""
     aux = jnp.zeros((), jnp.float32)
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if kind == "ssm":
-        if mode == "decode":
-            y, new_cache = apply_mamba2_decode(p["mixer"], h, cfg, cache)
+    with jax.named_scope("mixer"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        if kind == "ssm":
+            if mode == "decode":
+                y, new_cache = apply_mamba2_decode(p["mixer"], h, cfg, cache)
+            else:
+                y, new_cache = apply_mamba2(p["mixer"], h, cfg,
+                                            None if mode == "train" else None)
+                new_cache = new_cache if mode == "prefill" else None
+            return x + y, new_cache, aux
+        if kind == "rec":
+            y, new_cache = apply_rglru(p["mixer"], h, cfg,
+                                       cache if mode == "decode" else None)
+            if mode == "train":
+                new_cache = None
+        elif cfg.mla and kind in ("dense", "moe", "dense_mlp"):
+            y, new_cache = apply_mla_block(p["mixer"], h, cfg, mode=mode,
+                                           cache=cache, pos=pos)
         else:
-            y, new_cache = apply_mamba2(p["mixer"], h, cfg,
-                                        None if mode == "train" else None)
-            new_cache = new_cache if mode == "prefill" else None
-        return x + y, new_cache, aux
-    if kind == "rec":
-        y, new_cache = apply_rglru(p["mixer"], h, cfg,
-                                   cache if mode == "decode" else None)
-        if mode == "train":
-            new_cache = None
-    elif cfg.mla and kind in ("dense", "moe", "dense_mlp"):
-        y, new_cache = apply_mla_block(p["mixer"], h, cfg, mode=mode,
-                                       cache=cache, pos=pos)
-    else:
-        window = cfg.window if kind in ("dense", "moe", "dense_mlp") else cfg.window
-        if kind == "local_attn":
-            window = cfg.window or 2048
-        y, new_cache = apply_attn(p["mixer"], h, cfg, mode=mode, cache=cache,
-                                  pos=pos, window=window, prefix_len=prefix_len)
-    x = x + y
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if kind == "moe":
-        y2, aux = apply_moe(p["moe"], h2, cfg)
-    else:
-        y2 = apply_mlp(p["mlp"], h2, cfg.act)
-    x = x + y2
+            window = cfg.window if kind in ("dense", "moe", "dense_mlp") else cfg.window
+            if kind == "local_attn":
+                window = cfg.window or 2048
+            y, new_cache = apply_attn(p["mixer"], h, cfg, mode=mode, cache=cache,
+                                      pos=pos, window=window, prefix_len=prefix_len)
+        x = x + y
+    with jax.named_scope("moe" if kind == "moe" else "mlp"):
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if kind == "moe":
+            y2, aux = apply_moe(p["moe"], h2, cfg)
+        else:
+            y2 = apply_mlp(p["mlp"], h2, cfg.act)
+        x = x + y2
     x = maybe_shard(x, P(("pod", "data"), "model", None))
     return x, new_cache, aux
 
@@ -337,11 +370,12 @@ class LM:
         """tokens (B, S) → (logits (B, S, V), aux_loss). ``extra_embeddings``
         (B, P, D) are prepended (VLM patch / audio frame stubs)."""
         cfg = self.cfg
-        x = params["embed"][tokens]
-        if extra_embeddings is not None:
-            x = jnp.concatenate([extra_embeddings.astype(x.dtype), x], axis=1)
-            prefix_len = max(prefix_len, extra_embeddings.shape[1])
-        x = maybe_shard(x, P(("pod", "data"), "model", None))
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
+            if extra_embeddings is not None:
+                x = jnp.concatenate([extra_embeddings.astype(x.dtype), x], axis=1)
+                prefix_len = max(prefix_len, extra_embeddings.shape[1])
+            x = maybe_shard(x, P(("pod", "data"), "model", None))
         unit, n_units, tail = self.scan_groups()
         tail_first = bool(cfg.n_experts and cfg.n_dense_layers)
 
@@ -353,10 +387,6 @@ class LM:
                                       mode="train", prefix_len=prefix_len)
                 aux = aux + a
             return x, aux
-
-        aux0 = jnp.zeros((), jnp.float32)
-        if tail and tail_first:
-            x, aux0 = run_tail(x, aux0)
 
         block_fn = functools.partial(self._unit_apply, cfg=cfg, unit=unit,
                                      prefix_len=prefix_len)
@@ -371,12 +401,17 @@ class LM:
             x, a = block_fn(x, unit_params)
             return (x, aux + a), None
 
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), params["stack"],
-                                   unroll=cfg.scan_unroll)
-        if tail and not tail_first:
-            x, aux = run_tail(x, aux)
-        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-        logits = self._head(params, x)
+        with jax.named_scope("layers"):
+            aux0 = jnp.zeros((), jnp.float32)
+            if tail and tail_first:
+                x, aux0 = run_tail(x, aux0)
+            (x, aux), _ = jax.lax.scan(body, (x, aux0), params["stack"],
+                                       unroll=cfg.scan_unroll)
+            if tail and not tail_first:
+                x, aux = run_tail(x, aux)
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+            logits = self._head(params, x)
         return logits, aux
 
     @staticmethod
@@ -403,14 +438,15 @@ class LM:
              ) -> Tuple[jnp.ndarray, Dict]:
         logits, aux = self.apply(params, batch["tokens"], remat=remat,
                                  extra_embeddings=batch.get("extra_embeddings"))
-        labels = batch["labels"]
-        if logits.shape[1] != labels.shape[1]:      # VLM prefix rows carry no loss
-            logits = logits[:, -labels.shape[1]:]
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
-        nll = jnp.sum((lse - ll) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-        return nll + aux, {"nll": nll, "aux": aux}
+        with jax.named_scope("loss"):
+            labels = batch["labels"]
+            if logits.shape[1] != labels.shape[1]:      # VLM prefix rows carry no loss
+                logits = logits[:, -labels.shape[1]:]
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+            mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
+            nll = jnp.sum((lse - ll) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+            return nll + aux, {"nll": nll, "aux": aux}
 
     # -- prefill / decode -------------------------------------------------------------
     def prefill(self, params: Dict, tokens: jnp.ndarray, cache: Dict, *,
@@ -427,11 +463,12 @@ class LM:
     def _serve(self, params, tokens, cache, *, mode, pos,
                extra_embeddings=None):
         cfg = self.cfg
-        x = params["embed"][tokens]
         prefix_len = cfg.prefix_len
-        if extra_embeddings is not None:
-            x = jnp.concatenate([extra_embeddings.astype(x.dtype), x], axis=1)
-            prefix_len = max(prefix_len, extra_embeddings.shape[1])
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
+            if extra_embeddings is not None:
+                x = jnp.concatenate([extra_embeddings.astype(x.dtype), x], axis=1)
+                prefix_len = max(prefix_len, extra_embeddings.shape[1])
         unit, n_units, tail = self.scan_groups()
         tail_first = bool(cfg.n_experts and cfg.n_dense_layers)
         kinds = self.layer_kinds()
@@ -447,10 +484,6 @@ class LM:
                 new_tail[f"t{i}"] = nc if nc is not None else cache_tail[f"t{i}"]
             return x, new_tail
 
-        new_cache: Dict[str, Any] = {}
-        if tail and tail_first:
-            x, new_cache["tail"] = run_tail(x, cache["tail"])
-
         def body(x, xs):
             unit_params, unit_cache = xs
             new_uc = {}
@@ -461,13 +494,18 @@ class LM:
                 new_uc[f"u{i}"] = nc if nc is not None else unit_cache[f"u{i}"]
             return x, new_uc
 
-        x, stack_cache = jax.lax.scan(body, x, (params["stack"], cache["stack"]),
-                                      unroll=cfg.scan_unroll)
-        new_cache["stack"] = stack_cache
-        if tail and not tail_first:
-            x, new_cache["tail"] = run_tail(x, cache["tail"])
-        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-        logits = self._head(params, x[:, -1:])
+        new_cache: Dict[str, Any] = {}
+        with jax.named_scope("layers"):
+            if tail and tail_first:
+                x, new_cache["tail"] = run_tail(x, cache["tail"])
+            x, stack_cache = jax.lax.scan(body, x, (params["stack"], cache["stack"]),
+                                          unroll=cfg.scan_unroll)
+            new_cache["stack"] = stack_cache
+            if tail and not tail_first:
+                x, new_cache["tail"] = run_tail(x, cache["tail"])
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+            logits = self._head(params, x[:, -1:])
         return logits, new_cache
 
 

@@ -18,11 +18,16 @@ sys.path.insert(0, str(ROOT))
 import chip_smoke  # noqa: E402
 
 
+CACHE_FLAGS = ("jax_compilation_cache_dir", "jax_compilation_cache_include_metadata_in_key",
+               "jax_traceback_in_locations_limit")
+
+
 @pytest.fixture()
 def cache_dir_config():
-    before = jax.config.jax_compilation_cache_dir
+    before = {k: getattr(jax.config, k) for k in CACHE_FLAGS}
     yield
-    jax.config.update("jax_compilation_cache_dir", before)
+    for k, v in before.items():
+        jax.config.update(k, v)
 
 
 def test_compile_cache_honours_env(monkeypatch, cache_dir_config):
@@ -39,6 +44,31 @@ def test_compile_cache_defaults_to_fixed_repo_dir(monkeypatch,
     assert where == str(ROOT / ".jax_cache")
     assert jax.config.jax_compilation_cache_dir == where
     assert compile_cache.enable_compile_cache() == where      # stable
+
+
+@pytest.mark.parametrize("other", ["mlp", None])
+def test_compile_cache_key_holds_the_scopes(monkeypatch, cache_dir_config, other):
+    """Programs alike but for a named scope get cache entries of their
+    own, so neither loads the other's executable and its scopes."""
+    import jax.numpy as jnp
+    import numpy as np
+    from jax._src import cache_key, compiler
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    compile_cache.enable_compile_cache()
+
+    def key(scope):
+        def f(x):
+            if scope is None:
+                return jnp.sin(x) * 2
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 2
+        module = jax.jit(f).lower(jnp.ones(4)).compiler_ir("stablehlo")
+        return cache_key.get(module, np.array(jax.devices()[:1]),
+                             compiler.get_compile_options(1, 1), jax.devices()[0].client)
+
+    assert key("mixer") == key("mixer")
+    assert key("mixer") != key(other)
 
 
 def test_phase_serve_attention_reduced():
