@@ -2,6 +2,8 @@
 
 Sweeps shapes/dtypes per kernel and asserts allclose against ref.py.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +11,8 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (ROWS, VMEM_BUDGET, _tile_plan,
+                                          flash_attention)
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.ssd_scan import ssd_scan
 
@@ -24,11 +27,14 @@ def _tol(dtype):
 # ---------------------------------------------------------------- flash
 @pytest.mark.parametrize("B,S,H,KV,d", [
     (2, 256, 4, 2, 64),
-    (1, 384, 8, 8, 128),      # S % block_q != 0 (padding path)
+    (1, 384, 8, 8, 128),      # S % block_q != 0 under window 100 (bq 112)
     (2, 128, 4, 1, 64),       # MQA
     (1, 512, 16, 4, 32),
+    (1, 256, 16, 2, 32),      # G = 8
+    (1, 200, 32, 1, 64),      # MQA, S % block_q != 0 (bq = ROWS / 32)
 ])
-@pytest.mark.parametrize("window", [None, 128])
+# 100: a window shorter than block_k (128) and not a multiple of it
+@pytest.mark.parametrize("window", [None, 128, 100])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(B, S, H, KV, d, window, dtype):
     ks = jax.random.split(KEY, 3)
@@ -39,6 +45,86 @@ def test_flash_attention(B, S, H, KV, d, window, dtype):
     exp = ref.flash_attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("B,S,H,KV,d,window", [
+    (2, 256, 4, 2, 64, None),
+    (1, 384, 16, 2, 32, 100),
+    (1, 300, 8, 1, 64, 128),  # T % block_k != 0
+])
+def test_flash_attention_blocks_128(B, S, H, KV, d, window):
+    """Forced 128 x 128 blocks: many query and KV blocks per call."""
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (B, S, H, d), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, KV, d), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, KV, d), jnp.float32)
+    out = flash_attention(q, k, v, window=window, block_q=128, block_k=128,
+                          interpret=True)
+    exp = ref.flash_attention_ref(q, k, v, window=window)
+    np.testing.assert_allclose(out, exp, **_tol(jnp.float32))
+
+
+def test_flash_attention_bf16_weights_keep_16_bits():
+    """bf16 inputs, one 512-key tile: the output is the f32 reference
+    rounded to bf16 but for a few last-bit flips (RMS 2.5e-5 here). With
+    one bf16 term per softmax weight in PV it reads about 7e-4."""
+    ks = jax.random.split(KEY, 3)
+    q = (2 * jax.random.normal(ks[0], (1, 512, 8, 80))).astype(jnp.bfloat16)
+    k = (2 * jax.random.normal(ks[1], (1, 512, 2, 80))).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, 512, 2, 80)).astype(jnp.bfloat16)
+    out = flash_attention(q, k, v, interpret=True).astype(jnp.float32)
+    exp = ref.flash_attention_ref(*(x.astype(jnp.float32) for x in (q, k, v)))
+    rounded = exp.astype(jnp.bfloat16).astype(jnp.float32)
+    assert float(jnp.sqrt(jnp.mean((out - rounded) ** 2))) < 1e-4
+
+
+def _pipeline_walk(B, S, T, KV, bq, bk, window):
+    """Live steps and K/V copies of a causal flash grid, by brute force:
+    a tile is live where some (query, key) pair in it is visible; a step
+    names the nearest live KV block of its query block, and the pipeline
+    copies a block in only when the name differs from the step before."""
+    nq, nk = -(-S // bq), -(-T // bk)
+    live, copies, prev = 0, 0, None
+    for iq in range(nq):
+        qpos = np.arange(iq * bq, min(S, iq * bq + bq))[:, None]
+        vis = []
+        for ik in range(nk):
+            kpos = np.arange(ik * bk, min(T, ik * bk + bk))[None, :]
+            ok = kpos <= qpos
+            if window is not None:
+                ok &= kpos > qpos - window
+            vis.append(bool(ok.any()))
+        lo = vis.index(True)
+        hi = nk - 1 - vis[::-1].index(True)
+        assert all(vis[lo:hi + 1])
+        live += hi - lo + 1
+        for ik in range(nk):
+            name = min(max(ik, lo), hi)
+            copies += name != prev
+            prev = name
+    return B * KV * live, B * KV * copies
+
+
+@pytest.mark.parametrize("B,S,H,KV,d,window,steps", [
+    (8, 4096, 32, 8, 80, 4096, 4096),      # danube long prefill
+    (1, 4096, 32, 8, 80, 4096, 512),       # danube train step
+    (1, 4096, 16, 16, 128, None, 256),     # MHA
+    (1, 4096, 32, 1, 80, None, 512),       # MQA
+    (1, 4096, 16, 16, 128, 1000, None),    # a local window
+])
+def test_flash_tile_plan(B, S, H, KV, d, window, steps):
+    plan = _tile_plan(B, S, S, H, KV, d, window, True)
+    if steps is not None:
+        assert math.prod(plan.grid) == steps
+    assert plan.grid[:2] == (B, KV)
+    assert (H // KV) * plan.block_q <= ROWS
+    assert plan.block_q == S or plan.block_q % 16 == 0
+    assert plan.vmem_bytes <= VMEM_BUDGET
+    live, copies = _pipeline_walk(B, S, S, KV, plan.block_q, plan.block_k,
+                                  plan.window)
+    # dead steps fetch nothing: every copy is of a tile whose body runs
+    assert (plan.live, plan.fetched) == (live, copies)
+    assert plan.fetched <= plan.live < math.prod(plan.grid)
 
 
 def test_flash_attention_noncausal():

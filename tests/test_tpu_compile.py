@@ -81,3 +81,13 @@ def test_flash_attention_train_step_compiles_for_v5e(one_chip):
                           [((1, 4096, 32, 80), BF), ((1, 4096, 8, 80), BF),
                            ((1, 4096, 8, 80), BF)], one_chip)
     assert "tpu_custom_call" in text
+
+
+def test_flash_attention_mqa_compiles_for_v5e(one_chip):
+    """One KV head for 32 query heads: the whole group folds into one
+    tile of 32 x block_q rows, the largest group the plan folds."""
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        [((2, 4096, 32, 128), BF), ((2, 4096, 1, 128), BF),
+         ((2, 4096, 1, 128), BF)], one_chip)
+    assert re.search(r"%flash_attention\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
