@@ -16,7 +16,7 @@ from ..models.config import ArchConfig
 ARCH_IDS = [
     "qwen3_32b", "granite_20b", "h2o_danube_1_8b", "granite_8b",
     "mamba2_780m", "recurrentgemma_9b", "olmoe_1b_7b", "deepseek_v2_236b",
-    "whisper_small", "paligemma_3b",
+    "whisper_small", "paligemma_3b", "granite_4_0_h_micro",
 ]
 
 _ALIAS = {i.replace("_", "-"): i for i in ARCH_IDS}
@@ -63,7 +63,7 @@ def applicable_shapes(arch: str) -> List[ShapeSpec]:
 
 
 def all_cells() -> List[Tuple[str, ShapeSpec]]:
-    """Every assigned (arch × shape) cell (40 incl. documented skips)."""
+    """Every assigned (arch × shape) cell (44 incl. documented skips)."""
     cells = []
     for a in ARCH_IDS:
         for s in applicable_shapes(a):

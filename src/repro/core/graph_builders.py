@@ -34,9 +34,10 @@ class GraphSpec:
     # MoE
     n_experts: int = 0
     experts_per_token: int = 0
-    # SSM / hybrid
+    # SSM / hybrid: each layer's block kind ("ssm": an SSD block, with an
+    # MLP when d_ff > 0; anything else: attention); () = all attention
     ssm_state: int = 0
-    attn_free: bool = False
+    layer_kinds: Tuple[str, ...] = ()
     # enc-dec / multimodal branches: list of (branch_name, n_layers, d_model_branch, merge_proj)
     branches: Tuple[Tuple[str, int, int], ...] = ()
 
@@ -92,10 +93,13 @@ def build_lm_graph(spec: GraphSpec, seq_len: Optional[int] = None) -> ModelGraph
         name="embed", flops_fwd=0.0, param_bytes=BYTES * spec.vocab * spec.d_model,
         act_bytes=act)]
     for i in range(spec.n_layers):
-        if spec.attn_free and spec.ssm_state:
+        if spec.layer_kinds and spec.layer_kinds[i] == "ssm":
             fl = _ssm_flops(spec, seq)
             pb = _ssm_params(spec)
             state = BYTES * 2 * spec.d_model * spec.ssm_state
+            if spec.d_ff:
+                fl += _mlp_flops(spec, seq)
+                pb += _mlp_params(spec)
         else:
             fl = _attn_flops(spec, seq)
             pb = _attn_params(spec)

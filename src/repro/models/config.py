@@ -20,9 +20,15 @@ class ArchConfig:
     gated_mlp: bool = True
     act: str = "silu"              # silu | gelu
     qk_norm: bool = False
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0   # None: no positional encoding (NoPE)
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
+    attn_scale: Optional[float] = None      # softmax scale; None: 1/sqrt(head dim)
+
+    # -- Granite's scalars (each 1: no op) -------------------------------------------
+    embedding_multiplier: float = 1.0   # embedding output x this
+    residual_multiplier: float = 1.0    # each sublayer's output x this into the residual
+    logits_scaling: float = 1.0         # logits / this
 
     # -- attention variants --------------------------------------------------
     window: Optional[int] = None   # sliding-window attention (SWA)
@@ -53,10 +59,14 @@ class ArchConfig:
     ssm_expand: int = 2
     ssm_ngroups: int = 1
     ssm_conv: int = 4
+    ssm_conv_bias: bool = False
     ssm_chunk: int = 256
 
-    # -- hybrid (RecurrentGemma) -----------------------------------------------------
-    block_pattern: Tuple[str, ...] = ()    # e.g. ("rglru", "rglru", "attn")
+    # -- hybrid (RecurrentGemma, Granite 4.0-H) --------------------------------------
+    # one period of layer kinds, e.g. ("rec", "rec", "local_attn") or
+    # ("ssm",) * 5 + ("dense",) + ("ssm",) * 4; an "ssm" block carries a
+    # feed-forward when d_ff > 0
+    block_pattern: Tuple[str, ...] = ()
     lru_width: Optional[int] = None
     conv_width: int = 4
 
@@ -103,12 +113,7 @@ class ArchConfig:
         emb = self.padded_vocab * d * (1 if self.tie_embeddings else 2)
         per_layer = 0.0
         if self.ssm:
-            din = self.d_inner
-            per_layer = d * (2 * din + 2 * self.ssm_ngroups * self.ssm_state
-                             + self.ssm_nheads) + din * d \
-                + self.ssm_conv * (din + 2 * self.ssm_ngroups * self.ssm_state) \
-                + 2 * self.ssm_nheads
-            return emb + self.n_layers * per_layer
+            return emb + self.n_layers * self._ssd_params()
         if self.mla:
             attn = (d * self.q_lora_rank
                     + self.q_lora_rank * self.n_heads * (self.qk_nope_dim + self.qk_rope_dim)
@@ -134,16 +139,23 @@ class ArchConfig:
                 + self.n_enc_layers * (attn + dense_mlp)
             return total
         if self.block_pattern:
-            # hybrid: count recurrent vs attention blocks
+            # hybrid: each block by its kind; an SSD block's feed-forward is
+            # dense_mlp, which is 0 without one
             n = self.n_layers
             pat = [self.block_pattern[i % len(self.block_pattern)] for i in range(n)]
             lru = self.lru_dim
             rec = d * lru * 2 + lru * d + 2 * lru * self.conv_width + 4 * lru
-            total = emb
-            for kind in pat:
-                total += dense_mlp + (rec if kind == "rglru" else attn)
-            return total
+            mixer = {"rec": rec, "ssm": self._ssd_params()}
+            return emb + sum(dense_mlp + mixer.get(kind, attn) for kind in pat)
         return emb + self.n_layers * per
+
+    def _ssd_params(self) -> float:
+        """One Mamba-2 mixer: projections, conv (and its bias), a_log and dt_bias."""
+        d, din = self.d_model, self.d_inner
+        conv_dim = din + 2 * self.ssm_ngroups * self.ssm_state
+        return d * (2 * din + 2 * self.ssm_ngroups * self.ssm_state + self.ssm_nheads) \
+            + din * d + (self.ssm_conv + int(self.ssm_conv_bias)) * conv_dim \
+            + 2 * self.ssm_nheads
 
     def active_param_count(self) -> float:
         """Active (per-token) parameters — MoE top-k only."""

@@ -27,7 +27,7 @@ def planning_graph(cfg: ArchConfig, seq_len: int) -> ModelGraph:
         d_ff=cfg.d_ff or cfg.moe_d_ff, vocab=cfg.padded_vocab,
         head_dim=cfg.head_dim, gated_mlp=cfg.gated_mlp, seq_len=seq_len,
         n_experts=cfg.n_experts, experts_per_token=cfg.experts_per_token,
-        ssm_state=cfg.ssm_state, attn_free=cfg.ssm)
+        ssm_state=cfg.ssm_state, layer_kinds=LM(cfg).layer_kinds())
     if cfg.encdec:
         spec = GraphSpec(**{**spec.__dict__,
                             "branches": (("enc", cfg.n_enc_layers, cfg.d_model),)})

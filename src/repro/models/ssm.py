@@ -127,7 +127,7 @@ def init_mamba2(key, cfg: ArchConfig, dtype) -> Dict:
     g, n, h = cfg.ssm_ngroups, cfg.ssm_state, cfg.ssm_nheads
     ks = split_keys(key, 4)
     conv_dim = din + 2 * g * n
-    return {
+    p = {
         "in_proj": dense_init(ks[0], (d, 2 * din + 2 * g * n + h), dtype),
         "conv_w": dense_init(ks[1], (cfg.ssm_conv, conv_dim), dtype,
                              fan_in=cfg.ssm_conv),
@@ -137,15 +137,21 @@ def init_mamba2(key, cfg: ArchConfig, dtype) -> Dict:
         "norm_scale": jnp.zeros((din,), jnp.float32),
         "out_proj": dense_init(ks[2], (din, d), dtype, fan_in=din),
     }
+    if cfg.ssm_conv_bias:
+        p["conv_b"] = jnp.zeros((conv_dim,), dtype)
+    return p
 
 
-def _causal_conv(x: jnp.ndarray, w: jnp.ndarray,
+def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray | None,
                  tail: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Depthwise causal conv. x: (B, S, C); w: (K, C); tail: (B, K-1, C)."""
+    """Depthwise causal conv, then SiLU. x: (B, S, C); w: (K, C); b: (C,)
+    or None; tail: (B, K-1, C)."""
     K = w.shape[0]
     pad = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype) if tail is None else tail
     xp = jnp.concatenate([pad, x], axis=1)
     out = sum(xp[:, i:i + x.shape[1]] * w[i][None, None] for i in range(K))
+    if b is not None:
+        out = out + b
     return jax.nn.silu(out)
 
 
@@ -163,7 +169,7 @@ def apply_mamba2(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
     with jax.named_scope("conv"):
         conv_in = jnp.concatenate([xc, bc, cc], axis=-1)
         tail = state["conv"] if state is not None else None
-        conv_out = _causal_conv(conv_in, p["conv_w"], tail)
+        conv_out = _causal_conv(conv_in, p["conv_w"], p.get("conv_b"), tail)
         K = cfg.ssm_conv
         hist = conv_in if tail is None else jnp.concatenate([tail, conv_in], axis=1)
         if hist.shape[1] < K - 1:       # very short prefill: left-pad with zeros
@@ -218,8 +224,10 @@ def apply_mamba2_decode(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
     with jax.named_scope("conv"):
         conv_in = jnp.concatenate([xc, bc, cc], axis=-1)                 # (B,1,C)
         window = jnp.concatenate([state["conv"], conv_in], axis=1)       # (B,K,C)
-        w = p["conv_w"]
-        conv_out = jax.nn.silu(jnp.einsum("bkc,kc->bc", window, w))[:, None]
+        conv_out = jnp.einsum("bkc,kc->bc", window, p["conv_w"])
+        if "conv_b" in p:
+            conv_out = conv_out + p["conv_b"]
+        conv_out = jax.nn.silu(conv_out)[:, None]
         new_conv = window[:, 1:]
         xc, bc, cc = jnp.split(conv_out, [din, din + g * n], axis=-1)
 

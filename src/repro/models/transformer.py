@@ -11,8 +11,9 @@ One ``LM`` object per ``ArchConfig`` exposes:
 
 Layer stacks run under ``jax.lax.scan`` with stacked parameters (compile
 time at 512 devices stays flat in depth); heterogeneous-pattern models
-(RecurrentGemma 2:1, DeepSeek dense-first) scan over *pattern units*
-with the remainder unrolled.
+(RecurrentGemma 2:1, Granite 4.0-H 9 SSD : 1 attention, DeepSeek
+dense-first) scan over *pattern units* with the remainder unrolled; a
+unit's cache holds each of its blocks' state side by side.
 
 Every op runs under a ``jax.named_scope`` of one vocabulary, so that a
 profile names the sublayer that issued each device op: ``embed``,
@@ -82,8 +83,9 @@ def init_block(key, cfg: ArchConfig, kind: str, dtype) -> Dict:
     p: Dict[str, Any] = {"ln1": jnp.zeros((cfg.d_model,), jnp.float32)}
     if kind == "ssm":
         p["mixer"] = init_mamba2(ks[0], cfg, dtype)
-        return p
-    if kind == "rec":
+        if not cfg.d_ff:                  # Mamba-2 proper: no feed-forward
+            return p
+    elif kind == "rec":
         p["mixer"] = init_rglru(ks[0], cfg, dtype)
     elif kind in ("dense", "moe", "dense_mlp", "local_attn"):
         p["mixer"] = init_mla(ks[0], cfg, dtype) if cfg.mla \
@@ -107,8 +109,9 @@ def _project_qkv(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope_theta is not None:        # None: NoPE
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -140,9 +143,11 @@ def apply_attn(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
             if ring:
                 # ring holds exactly the in-window tokens; no window re-mask
                 valid = jnp.minimum(pos + 1, t_buf)
-                o = attn_lib.decode_attention(q, kc, vc, valid, window=None)
+                o = attn_lib.decode_attention(q, kc, vc, valid, window=None,
+                                              scale=cfg.attn_scale)
             else:
-                o = attn_lib.decode_attention(q, kc, vc, pos + 1, window=window)
+                o = attn_lib.decode_attention(q, kc, vc, pos + 1, window=window,
+                                              scale=cfg.attn_scale)
         with jax.named_scope("out"):
             out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
         return out, {"k": kc, "v": vc}
@@ -154,10 +159,11 @@ def apply_attn(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
         if S > cfg.attn_chunk:
             o = attn_lib.gqa_attention_chunked(q, k, v, causal=True, window=window,
                                                prefix_len=prefix_len,
-                                               q_chunk=cfg.attn_chunk // 4)
+                                               q_chunk=cfg.attn_chunk // 4,
+                                               scale=cfg.attn_scale)
         else:
             o = attn_lib.gqa_attention(q, k, v, causal=True, window=window,
-                                       prefix_len=prefix_len)
+                                       prefix_len=prefix_len, scale=cfg.attn_scale)
     with jax.named_scope("out"):
         out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     new_cache = None
@@ -234,7 +240,8 @@ def apply_block(p: Dict, x: jnp.ndarray, cfg: ArchConfig, kind: str, *,
                 prefix_len: int = 0) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
     """Returns (x_out, new_cache, aux_loss). The mixer and the
     feed-forward each run under a named scope (``mixer``, ``mlp`` or
-    ``moe``) that covers their pre-norm and residual."""
+    ``moe``) that covers their pre-norm and residual. An SSD block has a
+    feed-forward only when ``cfg.d_ff`` is set."""
     aux = jnp.zeros((), jnp.float32)
     with jax.named_scope("mixer"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -242,11 +249,11 @@ def apply_block(p: Dict, x: jnp.ndarray, cfg: ArchConfig, kind: str, *,
             if mode == "decode":
                 y, new_cache = apply_mamba2_decode(p["mixer"], h, cfg, cache)
             else:
-                y, new_cache = apply_mamba2(p["mixer"], h, cfg,
-                                            None if mode == "train" else None)
+                y, new_cache = apply_mamba2(p["mixer"], h, cfg, None)
                 new_cache = new_cache if mode == "prefill" else None
-            return x + y, new_cache, aux
-        if kind == "rec":
+            if not cfg.d_ff:
+                return x + _residual(y, cfg), new_cache, aux
+        elif kind == "rec":
             y, new_cache = apply_rglru(p["mixer"], h, cfg,
                                        cache if mode == "decode" else None)
             if mode == "train":
@@ -260,16 +267,26 @@ def apply_block(p: Dict, x: jnp.ndarray, cfg: ArchConfig, kind: str, *,
                 window = cfg.window or 2048
             y, new_cache = apply_attn(p["mixer"], h, cfg, mode=mode, cache=cache,
                                       pos=pos, window=window, prefix_len=prefix_len)
-        x = x + y
+        x = x + _residual(y, cfg)
     with jax.named_scope("moe" if kind == "moe" else "mlp"):
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
         if kind == "moe":
             y2, aux = apply_moe(p["moe"], h2, cfg)
         else:
             y2 = apply_mlp(p["mlp"], h2, cfg.act)
-        x = x + y2
+        x = x + _residual(y2, cfg)
     x = maybe_shard(x, P(("pod", "data"), "model", None))
     return x, new_cache, aux
+
+
+def _residual(y: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
+    """A sublayer's output as it enters the residual stream."""
+    return y if cfg.residual_multiplier == 1.0 else y * cfg.residual_multiplier
+
+
+def _embed(params: Dict, tokens: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
+    x = params["embed"][tokens]
+    return x if cfg.embedding_multiplier == 1.0 else x * cfg.embedding_multiplier
 
 
 # ==============================================================================
@@ -371,7 +388,7 @@ class LM:
         (B, P, D) are prepended (VLM patch / audio frame stubs)."""
         cfg = self.cfg
         with jax.named_scope("embed"):
-            x = params["embed"][tokens]
+            x = _embed(params, tokens, cfg)
             if extra_embeddings is not None:
                 x = jnp.concatenate([extra_embeddings.astype(x.dtype), x], axis=1)
                 prefix_len = max(prefix_len, extra_embeddings.shape[1])
@@ -427,6 +444,8 @@ class LM:
         cfg = self.cfg
         w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
         logits = (x @ w).astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         if cfg.padded_vocab != cfg.vocab_size:
             bias = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab_size,
                              0.0, attn_lib.NEG_INF)
@@ -465,7 +484,7 @@ class LM:
         cfg = self.cfg
         prefix_len = cfg.prefix_len
         with jax.named_scope("embed"):
-            x = params["embed"][tokens]
+            x = _embed(params, tokens, cfg)
             if extra_embeddings is not None:
                 x = jnp.concatenate([extra_embeddings.astype(x.dtype), x], axis=1)
                 prefix_len = max(prefix_len, extra_embeddings.shape[1])

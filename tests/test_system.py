@@ -39,8 +39,8 @@ def test_algorithm1_end_to_end():
 
 def test_assigned_cells_enumeration():
     cells = all_cells()
-    assert len(cells) == 33          # 40 assigned − 7 documented long_500k skips
-    assert len(ARCH_IDS) == 10
+    assert len(cells) == 36          # 44 assigned − 8 documented long_500k skips
+    assert len(ARCH_IDS) == 11
     for arch in ARCH_IDS:
         assert len(applicable_shapes(arch)) in (3, 4)
 
