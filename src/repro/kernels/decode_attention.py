@@ -1,22 +1,27 @@
 """Split-KV flash-decode attention as a Pallas TPU kernel.
 
-One new token attends to a (B, T, KV, d) cache. The KV sequence is
-split into tiles that stream through VMEM (the whole 32k decode cache
-never fits); the running (max, sum, acc) softmax state is carried in
-scratch across tiles — the same log-sum-exp rescaling that lets the
-sharded serve-path combine per-shard partial attention with a psum.
+One new token attends to a (B, T, KV, d) cache, or to layer ``layer`` of
+a stacked (L, B, T, KV, d) cache as the layer scan carries it, read where
+it lies with no copy of the layer. The KV sequence is split into tiles
+that stream through VMEM (the whole 32k decode cache never fits); the
+running (max, sum, acc) softmax state is carried in scratch across
+tiles — the same log-sum-exp rescaling that lets the sharded serve-path
+combine per-shard partial attention with a psum.
 
-Layout: the cache is viewed (free, row-major) as (B, T·KV, d), so a tile
-is ``block_k · KV`` rows by the full head dim — a shape Mosaic tiles for
-any KV count, with no transpose of the cache. All H query heads of a
-batch row are one (H, d) block, so one grid step scores every head
-against every row of the tile on the MXU; row r belongs to cache
-position r // KV and KV head r % KV, and a score survives only where the
-row's KV head is its query head's group (h // G). The KV-fold redundant
-multiply is cheap next to the cache read that bounds decode.
+Layout: the kernel reads the cache in the layout the TPU gives it, so
+that no copy of the cache precedes the call. A head size that is not a
+multiple of 128 lanes would be padded as the minor dim, so the TPU puts
+the positions minor: the cache lies as (L, B, KV, d, T), and a tile is
+(KV, d, block_k). A head size that is a multiple of 128 stays row-major,
+viewed (free) as (L, B, T, KV·d), and a tile is (block_k, KV·d), whose
+head h is the 128-aligned lane slice h·d:(h+1)·d. A per-layer cache is
+the case L = 1. Either way each KV head scores its G query heads, a
+(G, d) block of the (KV, G, d) query, against its (d, block_k) or
+(block_k, d) slice on the MXU.
 
-``cache_len`` (B,) arrives via scalar prefetch so the kernel masks
-invalid cache rows (and the ring-buffer window) without host branching.
+``cache_len`` (B,) and the layer index arrive via scalar prefetch: the
+kernel masks invalid cache positions (and the ring-buffer window)
+without host branching, and the K/V index maps pick the layer's tiles.
 
 Grid = (B, KV tiles); KV innermost/sequential.
 """
@@ -31,10 +36,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0e38
+TILE_BYTES = 1 << 21        # one K (or V) tile in VMEM, double-buffered
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                   *, scale: float, block_k: int, n_kv: int, group: int,
+def _decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+                   m_scr, l_scr, acc_scr, *, scale: float, block_k: int,
+                   n_kv: int, head_dim: int, pos_minor: bool, ragged: bool,
                    window: Optional[int], n_kblocks: int):
     b = pl.program_id(0)
     ik = pl.program_id(1)
@@ -47,35 +54,41 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     valid = len_ref[b]
     k_start = ik * block_k
-    run = k_start < valid
 
-    @pl.when(run)
+    @pl.when(k_start < valid)
     def _step():
-        q = q_ref[...]                                     # (H, d)
-        k = k_ref[...]                                     # (bk·KV, d)
-        v = v_ref[...]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = k_start + row // n_kv
-        ok = jnp.logical_and(row % n_kv == head // group, kpos < valid)
+        group = q_ref.shape[1]
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (group, block_k), 1)
+        ok = kpos < valid
         if window is not None:
             ok = jnp.logical_and(ok, kpos > valid - 1 - window)
-        s = jnp.where(ok, s, NEG_INF)
-        # rows past the end of a partial tile hold unspecified data
-        vrow = jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], 1), 0)
-        v = jnp.where(k_start + vrow // n_kv < valid, v, jnp.zeros_like(v))
+        if ragged:     # positions past the cache's end hold unspecified data
+            vshape = (head_dim, block_k) if pos_minor else (block_k, head_dim)
+            vpos = k_start + jax.lax.broadcasted_iota(jnp.int32, vshape,
+                                                      1 if pos_minor else 0)
+        # (d, bk) slices contract their first dim with q, their last with p
+        kc, vc = (0, 1) if pos_minor else (1, 0)
+        for h in range(n_kv):
+            if pos_minor:
+                k, v = k_ref[h], v_ref[h]                          # (d, bk)
+            else:
+                lanes = slice(h * head_dim, (h + 1) * head_dim)
+                k, v = k_ref[:, lanes], v_ref[:, lanes]            # (bk, d)
+            s = jax.lax.dot_general(q_ref[h], k, (((1,), (kc,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok, s, NEG_INF)                          # (G, bk)
+            if ragged:
+                v = jnp.where(vpos < valid, v, jnp.zeros_like(v))
 
-        m_prev = m_scr[...]                                # (H, 1)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_cur
+            m_prev = m_scr[h]                                      # (G, 1)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (vc,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_cur
 
     @pl.when(ik == n_kblocks - 1)
     def _finish():
@@ -87,46 +100,59 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 @functools.partial(
     jax.jit, static_argnames=("window", "scale", "block_k", "interpret"))
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
-                     v_cache: jnp.ndarray, cache_len: jnp.ndarray, *,
-                     window: Optional[int] = None,
-                     scale: Optional[float] = None, block_k: int = 256,
+                     v_cache: jnp.ndarray, cache_len: jnp.ndarray,
+                     layer=None, *, window: Optional[int] = None,
+                     scale: Optional[float] = None, block_k: int = 1024,
                      interpret: bool = False) -> jnp.ndarray:
-    """q: (B, 1, H, d); k/v cache: (B, T, KV, d); cache_len: (B,) int32.
-    Returns (B, 1, H, d)."""
+    """q: (B, 1, H, d); k/v cache: (B, T, KV, d), or (L, B, T, KV, d) with
+    ``layer`` the index (int32 scalar) of the layer to read; cache_len:
+    (B,) int32. Returns (B, 1, H, d). ``block_k`` (a multiple of 128)
+    caps the positions of a tile, which a ``TILE_BYTES`` tile caps too."""
+    if layer is None:
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
     B, _, H, d = q.shape
-    T, KV = k_cache.shape[1], k_cache.shape[2]
+    L, T, KV = k_cache.shape[0], k_cache.shape[2], k_cache.shape[3]
     G = H // KV
     scale_v = float(scale) if scale is not None else d ** -0.5
-    bk = min(block_k, T)
+    per_pos = KV * d * k_cache.dtype.itemsize
+    bk = min(block_k, max(128, TILE_BYTES // per_pos // 128 * 128))
+    bk = T if T <= bk else bk
     nk = pl.cdiv(T, bk)
-    rows = bk * KV
+    pos_minor = d % 128 != 0
+    if pos_minor:
+        k_view = jnp.transpose(k_cache, (0, 1, 3, 4, 2))       # (L, B, KV, d, T)
+        v_view = jnp.transpose(v_cache, (0, 1, 3, 4, 2))
+        kv_spec = pl.BlockSpec((None, None, KV, d, bk),
+                               lambda b, ik, lens, li: (li[0], b, 0, 0, ik))
+    else:
+        k_view = k_cache.reshape(L, B, T, KV * d)
+        v_view = v_cache.reshape(L, B, T, KV * d)
+        kv_spec = pl.BlockSpec((None, None, bk, KV * d),
+                               lambda b, ik, lens, li: (li[0], b, ik, 0))
 
     kernel = functools.partial(_decode_kernel, scale=scale_v, block_k=bk,
-                               n_kv=KV, group=G, window=window, n_kblocks=nk)
-
+                               n_kv=KV, head_dim=d, pos_minor=pos_minor,
+                               ragged=T % bk != 0, window=window, n_kblocks=nk)
+    qo_spec = pl.BlockSpec((None, KV, G, d), lambda b, ik, lens, li: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, nk),
-        in_specs=[
-            pl.BlockSpec((None, H, d), lambda b, ik, lens: (b, 0, 0)),
-            pl.BlockSpec((None, rows, d), lambda b, ik, lens: (b, ik, 0)),
-            pl.BlockSpec((None, rows, d), lambda b, ik, lens: (b, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, H, d), lambda b, ik, lens: (b, 0, 0)),
+        in_specs=[qo_spec, kv_spec, kv_spec],
+        out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, d), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         name="decode_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(cache_len.astype(jnp.int32), q.reshape(B, H, d),
-      k_cache.reshape(B, T * KV, d), v_cache.reshape(B, T * KV, d))
+    )(cache_len.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(B, KV, G, d), k_view, v_view)
     return out.reshape(B, 1, H, d)

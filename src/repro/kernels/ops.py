@@ -43,13 +43,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
                                    scale=scale)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len, *,
+def decode_attention(q, k_cache, v_cache, cache_len, layer=None, *,
                      window: Optional[int] = None,
                      scale: Optional[float] = None) -> jnp.ndarray:
     if use_pallas():
-        return _decode_pallas(q, k_cache, v_cache, cache_len, window=window,
-                              scale=scale)
-    return ref.decode_attention_ref(q, k_cache, v_cache, cache_len,
+        return _decode_pallas(q, k_cache, v_cache, cache_len, layer,
+                              window=window, scale=scale)
+    return ref.decode_attention_ref(q, k_cache, v_cache, cache_len, layer,
                                     window=window, scale=scale)
 
 

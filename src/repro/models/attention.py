@@ -109,9 +109,11 @@ def chunked_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
-                     cache_len: jnp.ndarray, *, window: Optional[int] = None,
+                     cache_len: jnp.ndarray, layer=None, *,
+                     window: Optional[int] = None,
                      scale: Optional[float] = None) -> jnp.ndarray:
-    """Single-position decode vs a (B, T, KV, hd) cache.
+    """Single-position decode vs a (B, T, KV, hd) cache, or vs layer
+    ``layer`` of a stacked (L, B, T, KV, hd) cache.
 
     q: (B, 1, H, hd); ``cache_len``: (B,) int32 — number of valid cache
     entries (the new token's k/v must already be written at
@@ -119,17 +121,20 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     """
     ops = _pallas_ops()
     if ops is not None:
-        return ops.decode_attention(q, k_cache, v_cache, cache_len,
+        return ops.decode_attention(q, k_cache, v_cache, cache_len, layer,
                                     window=window, scale=scale)
-    return decode_attention_ref(q, k_cache, v_cache, cache_len,
+    return decode_attention_ref(q, k_cache, v_cache, cache_len, layer,
                                 window=window, scale=scale)
 
 
 def decode_attention_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
-                         v_cache: jnp.ndarray, cache_len: jnp.ndarray, *,
-                         window: Optional[int] = None,
+                         v_cache: jnp.ndarray, cache_len: jnp.ndarray,
+                         layer=None, *, window: Optional[int] = None,
                          scale: Optional[float] = None) -> jnp.ndarray:
     """Pure-jnp decode attention (the kernel oracle — never dispatches)."""
+    if layer is not None:
+        k_cache = jax.lax.dynamic_index_in_dim(k_cache, layer, keepdims=False)
+        v_cache = jax.lax.dynamic_index_in_dim(v_cache, layer, keepdims=False)
     B, _, H, hd = q.shape
     KV = k_cache.shape[2]
     G = H // KV

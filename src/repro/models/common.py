@@ -46,6 +46,26 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0
     return out.astype(x.dtype)
 
 
+# -- stacked caches ----------------------------------------------------------------
+# A serving cache holds one (L, B, ...) buffer per leaf for the L layers of
+# a layer scan. The scan carries it; each layer reads its slice and writes
+# only what is new, so that under jit the donated buffer is updated in place.
+def layer_of(stack, i):
+    """Layer ``i``'s slice of a stacked (L, B, ...) cache tree."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), stack)
+
+
+def put_layer(stack, i, new, pos=0):
+    """Write each leaf of ``new`` (one layer's (B, n, ...) entries) into
+    layer ``i`` of the stacked (L, B, ...) tree, from position ``pos`` of
+    its third axis (T of a K/V cache)."""
+    def put(a, n):
+        start = (i, 0, pos) + (0,) * (a.ndim - 3)
+        return jax.lax.dynamic_update_slice(a, n[None].astype(a.dtype), start)
+    return jax.tree.map(put, stack, new)
+
+
 def activation(name: str):
     return {"silu": jax.nn.silu, "gelu": lambda x: jax.nn.gelu(x, approximate=True),
             "relu": jax.nn.relu}[name]

@@ -18,7 +18,7 @@ from .common import dense_init, dtype_of, embed_init, rms_norm, split_keys
 from .config import ArchConfig
 from .mlp import apply_mlp, init_mlp
 from .sharding_utils import maybe_shard
-from .transformer import _fit_cache, _write_cache, init_attn
+from .transformer import _fit_cache, init_attn
 
 
 def _init_enc_layer(key, cfg: ArchConfig, dtype) -> Dict:
@@ -59,6 +59,12 @@ def _cross_attn(p, x, k, v):
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"])
 
 
+def _write_cache(cache, kv, start):
+    """Write (B, n, KV, hd) into a (B, T, KV, hd) cache from position ``start``."""
+    return jax.lax.dynamic_update_slice_in_dim(cache, kv.astype(cache.dtype),
+                                               start, axis=1)
+
+
 def _self_attn(p, x, cfg, *, mode, cache, pos):
     from .common import apply_rope
     B, S, _ = x.shape
@@ -69,8 +75,8 @@ def _self_attn(p, x, cfg, *, mode, cache, pos):
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     if mode == "decode":
-        kc = _write_cache(cache["k"], k, pos)
-        vc = _write_cache(cache["v"], v, pos)
+        kc = _write_cache(cache["k"], k, pos[0])
+        vc = _write_cache(cache["v"], v, pos[0])
         o = attn_lib.decode_attention(q, kc, vc, pos + 1)
         return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), {"k": kc, "v": vc}
     if S > cfg.attn_chunk:
@@ -81,7 +87,9 @@ def _self_attn(p, x, cfg, *, mode, cache, pos):
         o = attn_lib.gqa_attention(q, k, v, causal=True)
     new_cache = None
     if mode == "prefill":
-        new_cache = {"k": _fit_cache(cache["k"], k), "v": _fit_cache(cache["v"], v)}
+        t_buf = cache["k"].shape[1]
+        new_cache = {"k": _write_cache(cache["k"], _fit_cache(t_buf, k), 0),
+                     "v": _write_cache(cache["v"], _fit_cache(t_buf, v), 0)}
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), new_cache
 
 

@@ -12,7 +12,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from .common import dense_init, rms_norm, split_keys
+from .common import dense_init, layer_of, put_layer, rms_norm, split_keys
 from .config import ArchConfig
 
 
@@ -212,8 +212,10 @@ def apply_mamba2(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
 
 
 def apply_mamba2_decode(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
-                        state: Dict) -> Tuple[jnp.ndarray, Dict]:
-    """Single-token recurrent update. x: (B, 1, D)."""
+                        state: Dict, layer) -> Tuple[jnp.ndarray, Dict]:
+    """Single-token recurrent update. x: (B, 1, D); ``state``: the stacked
+    {ssm (L, B, H, P, N), conv (L, B, K-1, C)} of the layer scan, whose
+    layer ``layer`` is read and written back in place."""
     B, S, D = x.shape
     din, g, n, h = cfg.d_inner, cfg.ssm_ngroups, cfg.ssm_state, cfg.ssm_nheads
     pdim = cfg.ssm_headdim
@@ -223,12 +225,13 @@ def apply_mamba2_decode(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
             proj, [din, 2 * din, 2 * din + g * n, 2 * din + 2 * g * n], axis=-1)
     with jax.named_scope("conv"):
         conv_in = jnp.concatenate([xc, bc, cc], axis=-1)                 # (B,1,C)
-        window = jnp.concatenate([state["conv"], conv_in], axis=1)       # (B,K,C)
+        tail = layer_of(state["conv"], layer)
+        window = jnp.concatenate([tail, conv_in], axis=1)                # (B,K,C)
         conv_out = jnp.einsum("bkc,kc->bc", window, p["conv_w"])
         if "conv_b" in p:
             conv_out = conv_out + p["conv_b"]
         conv_out = jax.nn.silu(conv_out)[:, None]
-        new_conv = window[:, 1:]
+        new_conv = put_layer(state["conv"], layer, window[:, 1:])
         xc, bc, cc = jnp.split(conv_out, [din, din + g * n], axis=-1)
 
     with jax.named_scope("scan"):
@@ -237,12 +240,12 @@ def apply_mamba2_decode(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
         xh = xc.reshape(B, h, pdim)
         bmat = jnp.repeat(bc.reshape(B, g, n), h // g, axis=1)              # (B,H,N)
         cmat = jnp.repeat(cc.reshape(B, g, n), h // g, axis=1)
-        hs = state["ssm"].astype(jnp.float32)
+        hs = layer_of(state["ssm"], layer).astype(jnp.float32)
         hs = a[..., None, None] * hs + (dt[..., None] * xh.astype(jnp.float32)
                                         )[..., None] * bmat[:, :, None, :].astype(jnp.float32)
         y = jnp.einsum("bhpn,bhn->bhp", hs, cmat.astype(jnp.float32)).astype(x.dtype)
         y = y + xh * p["d_skip"][None, :, None].astype(x.dtype)
-        new_ssm = hs.astype(state["ssm"].dtype)
+        new_ssm = put_layer(state["ssm"], layer, hs)
     with jax.named_scope("out"):
         y = y.reshape(B, 1, din)
         y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)

@@ -13,7 +13,9 @@ Layer stacks run under ``jax.lax.scan`` with stacked parameters (compile
 time at 512 devices stays flat in depth); heterogeneous-pattern models
 (RecurrentGemma 2:1, Granite 4.0-H 9 SSD : 1 attention, DeepSeek
 dense-first) scan over *pattern units* with the remainder unrolled; a
-unit's cache holds each of its blocks' state side by side.
+unit's cache holds each of its blocks' state side by side. Serving
+carries the stacked cache through the scan: each block writes only its
+new entries into its own layer of the one buffer, in place under jit.
 
 Every op runs under a ``jax.named_scope`` of one vocabulary, so that a
 profile names the sublayer that issued each device op: ``embed``,
@@ -34,7 +36,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from . import attention as attn_lib
-from .common import apply_rope, dense_init, dtype_of, embed_init, rms_norm, split_keys
+from .common import (apply_rope, dense_init, dtype_of, embed_init, layer_of,
+                     put_layer, rms_norm, split_keys)
 from .config import ArchConfig
 from .mlp import apply_mlp, apply_moe, init_mlp, init_moe
 from .rglru import apply_rglru, init_rglru, rglru_state_shape
@@ -116,9 +119,11 @@ def _project_qkv(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
 
 
 def apply_attn(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
-               cache: Optional[Dict], pos, window: Optional[int],
+               cache: Optional[Dict], layer, pos, window: Optional[int],
                prefix_len: int = 0,
                cross_kv: Optional[Tuple] = None) -> Tuple[jnp.ndarray, Optional[Dict]]:
+    """``cache``: the stacked {k, v} (L, B, T, KV, hd) of the layer scan;
+    the new K/V go to layer ``layer`` in place and decode reads them there."""
     B, S, D = x.shape
     if cross_kv is not None:          # encoder-decoder cross attention
         with jax.named_scope("qkv"):
@@ -133,24 +138,23 @@ def apply_attn(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
         positions = pos[:, None] if pos.ndim == 1 else pos
         with jax.named_scope("qkv"):
             q, k, v = _project_qkv(p, x, cfg, positions)
-        t_buf = cache["k"].shape[1]
+        t_buf = cache["k"].shape[2]
         ring = window is not None and t_buf <= window
         with jax.named_scope("cache"):
-            slot = pos % t_buf if ring else pos
-            kc = _write_cache(cache["k"], k, slot)
-            vc = _write_cache(cache["v"], v, slot)
+            slot = pos % t_buf if ring else pos          # uniform over the batch
+            new_cache = put_layer(cache, layer, {"k": k, "v": v}, slot[0])
         with jax.named_scope("attention"):
             if ring:
                 # ring holds exactly the in-window tokens; no window re-mask
-                valid = jnp.minimum(pos + 1, t_buf)
-                o = attn_lib.decode_attention(q, kc, vc, valid, window=None,
-                                              scale=cfg.attn_scale)
+                valid, win = jnp.minimum(pos + 1, t_buf), None
             else:
-                o = attn_lib.decode_attention(q, kc, vc, pos + 1, window=window,
-                                              scale=cfg.attn_scale)
+                valid, win = pos + 1, window
+            o = attn_lib.decode_attention(q, new_cache["k"], new_cache["v"], valid,
+                                          layer=layer, window=win,
+                                          scale=cfg.attn_scale)
         with jax.named_scope("out"):
             out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-        return out, {"k": kc, "v": vc}
+        return out, new_cache
 
     with jax.named_scope("qkv"):
         positions = jnp.arange(S)[None, :]
@@ -169,33 +173,25 @@ def apply_attn(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
     new_cache = None
     if mode == "prefill":
         with jax.named_scope("cache"):
-            kc = _fit_cache(cache["k"], k)
-            vc = _fit_cache(cache["v"], v)
-        new_cache = {"k": kc, "v": vc}
+            t_buf = cache["k"].shape[2]
+            new_cache = put_layer(cache, layer, {"k": _fit_cache(t_buf, k),
+                                                 "v": _fit_cache(t_buf, v)})
     return out, new_cache
 
 
-def _write_cache(cache: jnp.ndarray, kv: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
-    """Write (B,1,KV,hd) at per-batch position ``pos`` (uniform scalar)."""
-    return jax.lax.dynamic_update_slice_in_dim(cache, kv.astype(cache.dtype),
-                                               pos[0], axis=1)
-
-
-def _fit_cache(cache: jnp.ndarray, kv: jnp.ndarray) -> jnp.ndarray:
-    """Place prefill K/V into the cache buffer. When the prefill is longer
-    than a (windowed) ring buffer, keep the last T_buf entries laid out at
-    their ring slots (slot = absolute_pos % T_buf)."""
-    t_buf = cache.shape[1]
+def _fit_cache(t_buf: int, kv: jnp.ndarray) -> jnp.ndarray:
+    """Prefill K/V as they lie in a cache of ``t_buf`` positions. When the
+    prefill is longer than a (windowed) ring buffer, keep the last t_buf
+    entries laid out at their ring slots (slot = absolute_pos % t_buf)."""
     s = kv.shape[1]
     if s <= t_buf:
-        return jax.lax.dynamic_update_slice_in_dim(cache, kv.astype(cache.dtype),
-                                                   0, axis=1)
-    last = kv[:, -t_buf:].astype(cache.dtype)
-    return jnp.roll(last, s % t_buf, axis=1)
+        return kv
+    return jnp.roll(kv[:, -t_buf:], s % t_buf, axis=1)
 
 
 def apply_mla_block(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
-                    cache: Optional[Dict], pos) -> Tuple[jnp.ndarray, Optional[Dict]]:
+                    cache: Optional[Dict], layer,
+                    pos) -> Tuple[jnp.ndarray, Optional[Dict]]:
     B, S, D = x.shape
     with jax.named_scope("qkv"):
         cq = rms_norm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps)
@@ -208,15 +204,16 @@ def apply_mla_block(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
             k_rope_rot = apply_rope(k_rope[:, :, None, :], positions,
                                     cfg.rope_theta)[:, :, 0]
         with jax.named_scope("cache"):
-            ckv_c = _write_cache(cache["ckv"], ckv, pos)
-            kr_c = _write_cache(cache["krope"], k_rope_rot, pos)
+            new_cache = put_layer(cache, layer, {"ckv": ckv, "krope": k_rope_rot},
+                                  pos[0])
         with jax.named_scope("attention"):
-            o = attn_lib.mla_decode(cq, ckv_c, kr_c, pos + 1,
+            c = layer_of(new_cache, layer)
+            o = attn_lib.mla_decode(cq, c["ckv"], c["krope"], pos + 1,
                                     p["wq_nope"], p["wq_rope"], p["wk_nope"], p["wv"],
                                     rope_theta=cfg.rope_theta)
         with jax.named_scope("out"):
             out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-        return out, {"ckv": ckv_c, "krope": kr_c}
+        return out, new_cache
     with jax.named_scope("attention"):
         o = attn_lib.mla_prefill(cq, ckv, k_rope, p["wq_nope"], p["wq_rope"],
                                  p["wk_nope"], p["wv"], rope_theta=cfg.rope_theta,
@@ -230,43 +227,51 @@ def apply_mla_block(p: Dict, x: jnp.ndarray, cfg: ArchConfig, *, mode: str,
             k_rope_rot = apply_rope(k_rope[:, :, None, :], positions,
                                     cfg.rope_theta)[:, :, 0]
         with jax.named_scope("cache"):
-            new_cache = {"ckv": _fit_cache(cache["ckv"], ckv),
-                         "krope": _fit_cache(cache["krope"], k_rope_rot)}
+            t_buf = cache["ckv"].shape[2]
+            new_cache = put_layer(cache, layer,
+                                  {"ckv": _fit_cache(t_buf, ckv),
+                                   "krope": _fit_cache(t_buf, k_rope_rot)})
     return out, new_cache
 
 
 def apply_block(p: Dict, x: jnp.ndarray, cfg: ArchConfig, kind: str, *,
-                mode: str = "train", cache=None, pos=None,
+                mode: str = "train", cache=None, layer=None, pos=None,
                 prefix_len: int = 0) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
-    """Returns (x_out, new_cache, aux_loss). The mixer and the
-    feed-forward each run under a named scope (``mixer``, ``mlp`` or
-    ``moe``) that covers their pre-norm and residual. An SSD block has a
-    feed-forward only when ``cfg.d_ff`` is set."""
+    """Returns (x_out, new_cache, aux_loss). When serving, ``cache`` is the
+    block's stacked (L, B, ...) cache and ``layer`` its index there; the
+    block writes only what is new into that layer and returns the whole
+    stack (None in training). The mixer and the feed-forward each run
+    under a named scope (``mixer``, ``mlp`` or ``moe``) that covers their
+    pre-norm and residual. An SSD block has a feed-forward only when
+    ``cfg.d_ff`` is set."""
     aux = jnp.zeros((), jnp.float32)
+    new_cache = None
     with jax.named_scope("mixer"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         if kind == "ssm":
             if mode == "decode":
-                y, new_cache = apply_mamba2_decode(p["mixer"], h, cfg, cache)
+                y, new_cache = apply_mamba2_decode(p["mixer"], h, cfg, cache, layer)
             else:
-                y, new_cache = apply_mamba2(p["mixer"], h, cfg, None)
-                new_cache = new_cache if mode == "prefill" else None
+                y, state = apply_mamba2(p["mixer"], h, cfg, None)
+                if mode == "prefill":
+                    new_cache = put_layer(cache, layer, state)
             if not cfg.d_ff:
                 return x + _residual(y, cfg), new_cache, aux
         elif kind == "rec":
-            y, new_cache = apply_rglru(p["mixer"], h, cfg,
-                                       cache if mode == "decode" else None)
-            if mode == "train":
-                new_cache = None
+            y, state = apply_rglru(p["mixer"], h, cfg, layer_of(cache, layer)
+                                   if mode == "decode" else None)
+            if mode != "train":
+                new_cache = put_layer(cache, layer, state)
         elif cfg.mla and kind in ("dense", "moe", "dense_mlp"):
             y, new_cache = apply_mla_block(p["mixer"], h, cfg, mode=mode,
-                                           cache=cache, pos=pos)
+                                           cache=cache, layer=layer, pos=pos)
         else:
             window = cfg.window if kind in ("dense", "moe", "dense_mlp") else cfg.window
             if kind == "local_attn":
                 window = cfg.window or 2048
             y, new_cache = apply_attn(p["mixer"], h, cfg, mode=mode, cache=cache,
-                                      pos=pos, window=window, prefix_len=prefix_len)
+                                      layer=layer, pos=pos, window=window,
+                                      prefix_len=prefix_len)
         x = x + _residual(y, cfg)
     with jax.named_scope("moe" if kind == "moe" else "mlp"):
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -495,31 +500,35 @@ class LM:
             (kinds[cfg.n_layers - len(tail):] if tail else ())
 
         def run_tail(x, cache_tail):
+            # an unrolled block's cache is its own: a stack of one layer
             new_tail = {}
             for i, kind in enumerate(tail_kinds):
+                one = jax.tree.map(lambda a: a[None], cache_tail[f"t{i}"])
                 x, nc, _ = apply_block(params["tail"][f"t{i}"], x, cfg, kind,
-                                       mode=mode, cache=cache_tail[f"t{i}"],
+                                       mode=mode, cache=one, layer=0,
                                        pos=pos, prefix_len=prefix_len)
-                new_tail[f"t{i}"] = nc if nc is not None else cache_tail[f"t{i}"]
+                new_tail[f"t{i}"] = jax.tree.map(lambda a: a[0], nc)
             return x, new_tail
 
-        def body(x, xs):
-            unit_params, unit_cache = xs
-            new_uc = {}
-            for i, kind in enumerate(unit):
-                x, nc, _ = apply_block(unit_params[f"u{i}"], x, cfg, kind,
-                                       mode=mode, cache=unit_cache[f"u{i}"],
-                                       pos=pos, prefix_len=prefix_len)
-                new_uc[f"u{i}"] = nc if nc is not None else unit_cache[f"u{i}"]
-            return x, new_uc
+        def body(carry, xs):
+            # the stacked cache is carried, not scanned over: each block
+            # writes its new entries into layer i of the one buffer
+            x, stack = carry
+            unit_params, i = xs
+            stack = dict(stack)
+            for j, kind in enumerate(unit):
+                x, stack[f"u{j}"], _ = apply_block(
+                    unit_params[f"u{j}"], x, cfg, kind, mode=mode,
+                    cache=stack[f"u{j}"], layer=i, pos=pos, prefix_len=prefix_len)
+            return (x, stack), None
 
         new_cache: Dict[str, Any] = {}
         with jax.named_scope("layers"):
             if tail and tail_first:
                 x, new_cache["tail"] = run_tail(x, cache["tail"])
-            x, stack_cache = jax.lax.scan(body, x, (params["stack"], cache["stack"]),
-                                          unroll=cfg.scan_unroll)
-            new_cache["stack"] = stack_cache
+            (x, new_cache["stack"]), _ = jax.lax.scan(
+                body, (x, cache["stack"]), (params["stack"], jnp.arange(n_units)),
+                unroll=cfg.scan_unroll)
             if tail and not tail_first:
                 x, new_cache["tail"] = run_tail(x, cache["tail"])
         with jax.named_scope("head"):

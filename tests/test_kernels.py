@@ -145,27 +145,37 @@ def test_flash_attention_noncausal():
 ])
 @pytest.mark.parametrize("window", [None, 96])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_decode_attention(B, T, H, KV, d, window, dtype):
+# None: a per-layer (B, T, KV, d) cache; 2: layer 2 of a stacked
+# (L, B, T, KV, d) cache, read in place through the layer index
+@pytest.mark.parametrize("layer", [None, 2])
+def test_decode_attention(B, T, H, KV, d, window, dtype, layer):
     ks = jax.random.split(KEY, 4)
+    stack = () if layer is None else (4,)
     q = jax.random.normal(ks[0], (B, 1, H, d), dtype)
-    kc = jax.random.normal(ks[1], (B, T, KV, d), dtype)
-    vc = jax.random.normal(ks[2], (B, T, KV, d), dtype)
+    kc = jax.random.normal(ks[1], stack + (B, T, KV, d), dtype)
+    vc = jax.random.normal(ks[2], stack + (B, T, KV, d), dtype)
     lens = jnp.array([T // 3 + 1] * B, jnp.int32)
-    out = decode_attention(q, kc, vc, lens, window=window, interpret=True)
+    # 128-position tiles: several per sequence, the last one partial at T 300
+    out = decode_attention(q, kc, vc, lens, layer, window=window, block_k=128,
+                           interpret=True)
+    if layer is not None:
+        kc, vc = kc[layer], vc[layer]
     exp = ref.decode_attention_ref(q, kc, vc, lens, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), **_tol(dtype))
 
 
-def test_decode_attention_per_batch_lengths():
+@pytest.mark.parametrize("layer", [None, 1])
+def test_decode_attention_per_batch_lengths(layer):
     ks = jax.random.split(KEY, 4)
     B, T, H, d = 4, 256, 4, 64
+    stack = () if layer is None else (3,)
     q = jax.random.normal(ks[0], (B, 1, H, d), jnp.float32)
-    kc = jax.random.normal(ks[1], (B, T, H, d), jnp.float32)
-    vc = jax.random.normal(ks[2], (B, T, H, d), jnp.float32)
+    kc = jax.random.normal(ks[1], stack + (B, T, H, d), jnp.float32)
+    vc = jax.random.normal(ks[2], stack + (B, T, H, d), jnp.float32)
     lens = jnp.array([1, 17, 100, 256], jnp.int32)
-    out = decode_attention(q, kc, vc, lens, interpret=True)
-    exp = ref.decode_attention_ref(q, kc, vc, lens)
+    out = decode_attention(q, kc, vc, lens, layer, block_k=128, interpret=True)
+    exp = ref.decode_attention_ref(q, kc, vc, lens, layer)
     np.testing.assert_allclose(out, exp, atol=2e-5, rtol=2e-5)
 
 

@@ -109,6 +109,49 @@ def test_prefill_decode_consistency(arch):
         np.asarray(logits_full[:, -1], np.float32), atol=2e-3, rtol=2e-3)
 
 
+@pytest.mark.parametrize("arch", ["h2o_danube_1_8b", "mamba2_780m",
+                                  "granite_4_0_h_micro"])
+def test_decode_writes_the_stacked_cache_in_place(arch):
+    """One decode step through the layer scan, on a cache of random
+    entries: each attention layer's K/V differ from before only at the
+    written slot, where they hold that layer's new row; each SSD layer's
+    {ssm, conv} equal that layer's update computed apart (the layers
+    walked one by one, each with a cache of its own)."""
+    from repro.models.transformer import _embed, apply_block
+    cfg = reduced_config(arch)
+    model = build_model(cfg)
+    rng = jax.random.PRNGKey(4)
+    params = model.init(rng)
+    cache = model.init_cache(B, 48)           # danube: a ring of its window, 32
+    keys = iter(jax.random.split(rng, len(jax.tree.leaves(cache))))
+    cache = jax.tree.map(lambda a: (0.5 * jax.random.normal(next(keys), a.shape)
+                                    ).astype(a.dtype), cache)
+    P = 40
+    pos = jnp.full((B,), P, jnp.int32)
+    tok = jax.random.randint(rng, (B, 1), 0, cfg.vocab_size)
+    _, new = jax.jit(model.decode)(params, tok, cache, pos)
+
+    unit, n_units, tail = model.scan_groups()
+    assert not tail
+    x = _embed(params, tok, cfg)
+    for i in range(n_units):
+        for j, kind in enumerate(unit):
+            layer = lambda tree: jax.tree.map(lambda a: a[i], tree)
+            old, got = layer(cache["stack"][f"u{j}"]), layer(new["stack"][f"u{j}"])
+            x, apart, _ = apply_block(layer(params["stack"][f"u{j}"]), x, cfg,
+                                      kind, mode="decode", layer=0, pos=pos,
+                                      cache=jax.tree.map(lambda a: a[None], old))
+            for name, a in got.items():
+                a, b, want = np.asarray(a), np.asarray(old[name]), np.asarray(apart[name][0])
+                if kind == "ssm":
+                    np.testing.assert_allclose(a, want, rtol=1e-5, atol=1e-5)
+                    continue
+                slot = P % a.shape[1]
+                np.testing.assert_array_equal(np.delete(a, slot, 1), np.delete(b, slot, 1))
+                np.testing.assert_allclose(a[:, slot], want[:, slot], rtol=1e-5, atol=1e-5)
+                assert not np.allclose(a[:, slot], b[:, slot])
+
+
 def test_encdec_roundtrip():
     cfg = reduced_config("whisper_small")
     model = build_model(cfg)
